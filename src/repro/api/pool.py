@@ -12,8 +12,11 @@ for sweep-scale robustness:
   so the jax import is paid once and worker-local simulator caches stay
   warm between sweeps (the steady-state throughput win);
 * **fork where safe** — the default context is ``fork`` when the platform
-  offers it (workers inherit the parent's already-imported jax at zero
-  cost) with ``spawn`` as the fallback; pass ``mp_context=`` to override;
+  offers it and the parent's jax backend is the CPU (workers inherit the
+  parent's already-imported jax at zero cost), else ``spawn``: an
+  accelerator client is not fork-safe.  Workers only trace, so they pin
+  themselves to the CPU and never touch a chip the parent holds; pass
+  ``mp_context=`` to override the context;
 * **per-candidate execution contracts** — each candidate is dispatched as
   its own task with a wall-clock timeout; workers send ``started`` markers,
   results, and daemon-thread heartbeats, so the parent can tell a slow
@@ -132,6 +135,10 @@ def _worker_main(wid: int, seq: int, task_q, wconn, parent_pid: int,
     never strand a half-written message, and dying mid-send poisons only
     a pipe that is discarded with this incarnation."""
     signal.signal(signal.SIGINT, signal.SIG_IGN)
+    # before any jax work: a spawned worker must not open the accelerator
+    # (a no-op in a fork child, whose parent already runs on the CPU)
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
     stop = threading.Event()
     send_lock = threading.Lock()            # beat + main thread share wconn
@@ -649,10 +656,15 @@ def _compact_tb(tb: str, max_lines: int = 12) -> str:
 
 
 def default_context() -> str:
-    """``fork`` where the platform offers it (workers inherit the parent's
-    imported jax — near-zero startup), else ``spawn``."""
+    """``fork`` where the platform offers it and the parent's jax backend is
+    the CPU (workers inherit the parent's imported jax — near-zero
+    startup), else ``spawn``: a forked accelerator client is not safe."""
     import multiprocessing as mp
-    return "fork" if "fork" in mp.get_all_start_methods() else "spawn"
+
+    import jax
+    if "fork" in mp.get_all_start_methods() and jax.default_backend() == "cpu":
+        return "fork"
+    return "spawn"
 
 
 # --------------------------------------------------------------------------

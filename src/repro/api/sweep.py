@@ -497,8 +497,9 @@ def sweep(space: SweepSpace, *, sim: Simulator | None = None,
     long-lived :class:`~repro.api.pool.WorkerPool` (a process-wide
     singleton: the second sweep reuses warm workers, skipping the spawn +
     jax-import tax and keeping worker-local simulator caches hot).
-    ``mp_context=None`` picks ``fork`` where the platform offers it, else
-    ``spawn``.  Results, rankings and pruned reasons are bit-identical to
+    ``mp_context=None`` picks ``fork`` where the platform offers it and
+    this process runs jax on the CPU, else ``spawn``; workers are pinned to
+    the CPU either way.  Results, rankings and pruned reasons are bit-identical to
     the serial sweep, with the merged ``cache_stats`` summing the
     per-worker deltas.  ``sim=`` is not used for evaluation in that case
     (worker processes own their simulators); pass ``persist=`` (a

@@ -107,6 +107,19 @@ XLA_CPU = HardwareSpec(
 
 HARDWARE = {h.name: h for h in (TPU_V5E, TPU_V5P, A100_80G, H100_SXM, XLA_CPU)}
 
+# jax ``Device.device_kind`` -> the spec of that chip.  Only kinds a run has
+# reported belong here; an unknown kind is an error, never a default.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
 
 def get_hardware(name: str) -> HardwareSpec:
     return HARDWARE[name]
+
+
+def hardware_for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of the chip jax reports as ``device_kind``."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no HardwareSpec for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None

@@ -32,11 +32,14 @@ def node_key(node: OpNode, hw_name: str) -> str:
 
 
 class ProfileDB:
-    def __init__(self, path: Path | str = DB_PATH):
-        self.path = Path(path)
+    """Measured latencies keyed by :func:`node_key`, loaded from ``path``;
+    ``path=None`` keeps the database in memory and reads no file."""
+
+    def __init__(self, path: Path | str | None = DB_PATH):
+        self.path = Path(path) if path is not None else None
         self.data: dict[str, dict] = {}
         self.version = 0     # bumped on every put; price caches key on it
-        if self.path.exists():
+        if self.path is not None and self.path.exists():
             try:
                 self.data = json.loads(self.path.read_text())
             except Exception:
@@ -51,6 +54,8 @@ class ProfileDB:
         self.data[key] = {"us": us, **meta}
 
     def save(self):
+        if self.path is None:
+            raise ValueError("an in-memory ProfileDB has no file to save to")
         self.path.parent.mkdir(parents=True, exist_ok=True)
         self.path.write_text(json.dumps(self.data, indent=0))
 

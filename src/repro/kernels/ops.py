@@ -1,9 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute in ``interpret=True`` mode for
-correctness validation; on a TPU runtime they compile to Mosaic.  The
-wrappers auto-select, and layout-adapt from the model's (B, S, H, D) tensors
-to the kernels' (B, H, S, D).
+Kernels compile to Mosaic for the TPU.  ``interpret=True`` runs them in the
+Pallas interpreter instead (CPU correctness tests); it is an explicit
+argument, never chosen from the backend.  The wrappers layout-adapt from the
+model's (B, S, H, D) tensors to the kernels' (B, H, S, D).
 """
 from __future__ import annotations
 
@@ -17,42 +17,42 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    interpret: bool = False):
     """q: (B,H,Sq,D); k/v: (B,Hkv,Sk,D)."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=_interpret())
+                               interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window"))
-def flash_attention_bshd(q, k, v, *, causal: bool = True, window: int = 0):
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def flash_attention_bshd(q, k, v, *, causal: bool = True, window: int = 0,
+                         interpret: bool = False):
     """Model layout: q (B,S,Hkv,G,D); k/v (B,T,Hkv,D) -> (B,S,Hkv,G,D)."""
     B, S, Hkv, G, D = q.shape
     qh = q.reshape(B, S, Hkv * G, D).transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     o = _fa.flash_attention(qh, kh, vh, causal=causal, window=window,
-                            interpret=_interpret())
+                            interpret=interpret)
     return o.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
 
 
-@jax.jit
-def decode_attention(q, k, v, kv_valid_len=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_attention(q, k, v, kv_valid_len=None, *, interpret: bool = False):
     """q: (B,H,D); k/v: (B,Hkv,T,D)."""
     return _dec.decode_attention(q, k, v, kv_valid_len=kv_valid_len,
-                                 interpret=_interpret())
+                                 interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "offset"))
-def rmsnorm(x, w, *, eps: float = 1e-6, offset: bool = False):
-    return _rn.rmsnorm(x, w, eps=eps, offset=offset, interpret=_interpret())
+@functools.partial(jax.jit, static_argnames=("eps", "offset", "interpret"))
+def rmsnorm(x, w, *, eps: float = 1e-6, offset: bool = False,
+            interpret: bool = False):
+    return _rn.rmsnorm(x, w, eps=eps, offset=offset, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "offset"))
-def rmsnorm_residual(x, residual, w, *, eps: float = 1e-6, offset: bool = False):
+@functools.partial(jax.jit, static_argnames=("eps", "offset", "interpret"))
+def rmsnorm_residual(x, residual, w, *, eps: float = 1e-6, offset: bool = False,
+                     interpret: bool = False):
     return _rn.rmsnorm(x, w, eps=eps, offset=offset, residual=residual,
-                       interpret=_interpret())
+                       interpret=interpret)

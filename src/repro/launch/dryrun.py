@@ -1,14 +1,6 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST be the first lines: jax locks the device count on first init.
-# The dry-run (and ONLY the dry-run) fakes 512 host devices so the
-# production meshes (16x16 single-pod, 2x16x16 multi-pod) can be built.
-
 import argparse
 import json
-import re
-import sys
+import os
 import time
 import traceback
 from pathlib import Path
@@ -28,12 +20,18 @@ from repro.models import Model, abstract_params, count_params
 from repro.models.kvcache import build_cache
 from repro.training.optimizer import make_optimizer
 from repro.training.train_step import (
-    batch_pspecs, make_train_step, param_pspecs, state_pspecs, to_named,
+    batch_pspecs, jit_sharded_train_step, param_pspecs, to_named,
 )
 
 from repro.launch.hlo_analysis import analyze_module
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
+
+# The dry-run fakes 512 host devices so the production meshes (16x16
+# single-pod, 2x16x16 multi-pod) can be built.  jax fixes the device count
+# when its backend first starts, so a main() sets this before any jax call;
+# importing this module changes nothing.
+FAKE_DEVICES_FLAGS = "--xla_force_host_platform_device_count=512"
 
 
 # ---------------------------------------------------------------------------
@@ -97,13 +95,10 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
         if shape.kind == "train":
             optimizer = make_optimizer(run.optimizer)
-            step = make_train_step(cfg, run, optimizer)
             opt_abs = jax.eval_shape(optimizer.init, params_abs)
             state_abs = {"params": params_abs, "opt": opt_abs,
                          "step": jax.ShapeDtypeStruct((), jnp.int32)}
-            s_ns = to_named(env, state_pspecs(cfg, env, run))
-            jitted = jax.jit(step, in_shardings=(s_ns, b_ns), out_shardings=(s_ns, None),
-                             donate_argnums=(0,))
+            jitted, _ = jit_sharded_train_step(cfg, run, optimizer, env)
             lowered = jitted.lower(state_abs, batch_abs)
         elif shape.kind == "prefill":
             model = Model(cfg)
@@ -193,6 +188,7 @@ def run_cell_to_file(arch: str, shape_name: str, multi_pod: bool) -> dict:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = FAKE_DEVICES_FLAGS
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default=None, choices=list(ARCH_IDS))
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

@@ -1,17 +1,15 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf hillclimbing driver (§Perf): lower a cell with a named change,
 extract the three roofline terms, and log hypothesis -> before -> after.
 
     PYTHONPATH=src python -m repro.launch.perf_iter <experiment>
 """
 import json
+import os
 import sys
 import time
 from pathlib import Path
 
-from repro.launch.dryrun import lower_cell
+from repro.launch.dryrun import FAKE_DEVICES_FLAGS, lower_cell
 from repro.launch.roofline import cell_terms
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "perf"
@@ -70,6 +68,7 @@ def run_experiment(name: str) -> dict:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = FAKE_DEVICES_FLAGS
     names = sys.argv[1:] or list(EXPERIMENTS)
     for name in names:
         if name not in EXPERIMENTS:

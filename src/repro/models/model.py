@@ -1,7 +1,7 @@
 """Model assembly: one implementation covering all ten assigned architectures.
 
 ``Model`` exposes:
-  * ``init(rng)``                          — concrete params (tiny configs)
+  * ``init(rng)``                          — concrete params (jit it at full width)
   * ``forward(params, batch)``             — full-sequence logits (train)
   * ``prefill(params, batch, cache_len)``  — logits + populated KV/state cache
   * ``decode_step(params, cache, batch)``  — one token with a seq_len cache

@@ -325,7 +325,8 @@ def param_logical_axes(cfg: ModelConfig):
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array, dtype=None):
-    """Concrete init (tiny configs only — full configs are dry-run-only)."""
+    """Concrete init.  At published widths, jit it (``jax.jit(Model(cfg).init)``)
+    so the weights are generated on the device in one program."""
     dt = dtype or jnp.dtype(cfg.param_dtype)
     counter = [0]
 

@@ -161,3 +161,17 @@ def state_pspecs(cfg: ModelConfig, env: ShardingEnv, run: RunConfig) -> dict:
 def to_named(env: ShardingEnv, tree: Pytree) -> Pytree:
     return jax.tree.map(lambda s: NamedSharding(env.mesh, s), tree,
                         is_leaf=lambda x: isinstance(x, P))
+
+
+def jit_sharded_train_step(cfg: ModelConfig, run: RunConfig,
+                           optimizer: Optimizer, env: ShardingEnv):
+    """The train step jitted over ``env.mesh``: state (ZeRO-staged) and
+    batch sharded by the logical-axis rules, state donated.  Returns
+    ``(step, state_shardings)``; trace and call it under ``activate(env)``
+    so the model's activation constraints resolve on the same mesh."""
+    s_ns = to_named(env, state_pspecs(cfg, env, run))
+    b_ns = to_named(env, batch_pspecs(cfg, env, run.shape.global_batch))
+    step = jax.jit(make_train_step(cfg, run, optimizer),
+                   in_shardings=(s_ns, b_ns), out_shardings=(s_ns, None),
+                   donate_argnums=(0,))
+    return step, s_ns

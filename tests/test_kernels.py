@@ -54,7 +54,7 @@ def test_decode_attention_sweep(case):
     k = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, Hkv, T, D), jnp.float32).astype(dtype)
     vl = jnp.asarray([T // 2, T][:B], jnp.int32)
-    out = ops.decode_attention(q, k, v, vl)
+    out = ops.decode_attention(q, k, v, vl, interpret=True)
     want = ref.decode_attention_ref(q, k, v, kv_valid_len=vl)
     np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
                                atol=tol, rtol=tol)
@@ -67,7 +67,7 @@ def test_rmsnorm_property(rows, d, offset, bf16):
     dtype = jnp.bfloat16 if bf16 else jnp.float32
     x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32).astype(dtype)
     w = jax.random.normal(jax.random.PRNGKey(d), (d,), jnp.float32) * 0.1 + 1.0
-    out = ops.rmsnorm(x, w, offset=offset)
+    out = ops.rmsnorm(x, w, offset=offset, interpret=True)
     want = ref.rmsnorm_ref(x, w, offset=offset)
     tol = 3e-2 if bf16 else 2e-6
     np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
@@ -78,7 +78,7 @@ def test_rmsnorm_fused_residual():
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 10, 256))
     r = jax.random.normal(jax.random.PRNGKey(1), (4, 10, 256))
     w = jnp.ones((256,))
-    out = ops.rmsnorm_residual(x, r, w)
+    out = ops.rmsnorm_residual(x, r, w, interpret=True)
     want = ref.rmsnorm_ref(x, w, residual=r)
     np.testing.assert_allclose(out, want, atol=2e-6, rtol=2e-6)
 
@@ -90,7 +90,7 @@ def test_flash_matches_model_layout():
     q = jax.random.normal(jax.random.PRNGKey(0), (B, S, Hkv, G, D))
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, Hkv, D))
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, Hkv, D))
-    out = ops.flash_attention_bshd(q, k, v, causal=True)
+    out = ops.flash_attention_bshd(q, k, v, causal=True, interpret=True)
     want = L.attend_blockwise(q, k, v, q_offset=0, causal=True,
                               q_block=64, kv_block=64)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)

@@ -157,6 +157,16 @@ def test_memory_fit_estimate_is_lower_bound():
         assert rule(CFG, Candidate(par, gb)) is None
 
 
+def test_in_memory_profile_db_reads_and_writes_no_file():
+    from repro.core.backend.profiling import ProfileDB
+    db = ProfileDB(None)
+    assert db.path is None and not db.data
+    db.put("k", 1.0, {})
+    assert db.get("k") == 1.0
+    with pytest.raises(ValueError):
+        db.save()
+
+
 def test_pricing_cache_invalidated_on_profile_db_mutation():
     # the §3.3 workflow: simulate with an empty DB (analytical fallback),
     # then add measured profiles — re-simulation must pick them up

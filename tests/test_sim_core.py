@@ -233,3 +233,10 @@ def test_analysis_pipeline_flops_pre_post_recompute():
     assert res["executed_flops"] == pytest.approx(3e9)  # fwd recomputed in bwd
     assert res["recompute_overhead"] == pytest.approx(0.5)
     assert 0 < mfu(1e12, 1e6, 1, 197e12) < 1
+
+
+def test_device_kind_table_maps_known_chips_only():
+    from repro.core.backend.hardware import hardware_for_device_kind
+    assert hardware_for_device_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no HardwareSpec"):
+        hardware_for_device_kind("TPU v9 imaginary")

@@ -137,9 +137,6 @@ def test_dryrun_records_have_roofline_inputs():
 
 # ---------------- multi-device MoE equivalence (shard_map EP path) --------
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available in this jax version")
 def test_moe_sharded_matches_local():
     """Run the tiny MoE under a real 4-device mesh (subprocess so the fake
     device count cannot leak into this process)."""

@@ -242,13 +242,6 @@ def test_step_monitor_detects_straggler():
 
 # ---------------- sharding resolver ----------------
 
-# jax.sharding.AxisType landed after the pinned jax 0.4.37; skip (instead of
-# CI-level --deselect) so a local `pytest -x -q` matches CI with no flags
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available in this jax version")
-
-
 def _env(shape=(4, 2), axes=("data", "model")):
     # AbstractMesh: the resolver only needs axis names/sizes (1-device CI)
     mesh = jax.sharding.AbstractMesh(
@@ -256,7 +249,6 @@ def _env(shape=(4, 2), axes=("data", "model")):
     return ShardingEnv(mesh)
 
 
-@needs_axis_type
 def test_resolver_divisibility_fallback():
     env = _env()
     # 6 heads on a 2-wide model axis: shardable; 7: dropped
@@ -267,7 +259,6 @@ def test_resolver_divisibility_fallback():
     assert len(spec2) == 1  # model axis dropped
 
 
-@needs_axis_type
 def test_resolver_no_axis_reuse():
     env = _env()
     spec = resolve_spec(env, ("heads", "ffn"), (4, 4))  # both want 'model'
@@ -275,7 +266,6 @@ def test_resolver_no_axis_reuse():
     assert used.count("model") <= 1
 
 
-@needs_axis_type
 def test_fsdp_spec_adds_data_axis():
     env = _env()
     spec = fsdp_spec(env, ("layer", None, "ffn"), (3, 8, 4), skip_leading=1)
