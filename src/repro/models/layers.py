@@ -262,6 +262,22 @@ def linear(p: dict, x: jax.Array) -> jax.Array:
     return y
 
 
+def named_scope(scope: str | None = None):
+    """Decorator: run the function under ``jax.named_scope(scope)``, so its
+    ops carry ``/<scope>/`` in their HLO ``op_name`` and a device op in a
+    profile can be put under attention, the FFN or the head; the math is
+    unchanged.  Without ``scope``, the block kind (the function's second
+    argument) names it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope or args[1]):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+@named_scope("ffn")
 def ffn(cfg, p: dict, x: jax.Array) -> jax.Array:
     """SwiGLU / GeGLU / plain-GELU feed-forward."""
     if cfg.act in ("swiglu", "geglu"):
@@ -320,6 +336,7 @@ def _expert_mlp(p: dict, buf: jax.Array, dtype) -> jax.Array:
     return jnp.einsum("ecf,efd->ecd", h, p["down"].astype(dtype))
 
 
+@named_scope("moe")
 def moe_ffn(cfg, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Top-k routed experts with true expert parallelism.
 

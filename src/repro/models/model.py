@@ -63,6 +63,7 @@ def _attn_shardings(cfg):
     return q_ax, kv_ax
 
 
+@L.named_scope("attn")
 def gqa_full(cfg, p, x, positions, *, causal=True, window=0, rope=True):
     """Full-sequence GQA/MQA/MHA attention."""
     B, S, _ = x.shape
@@ -84,6 +85,7 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0, rope=True):
     return _attn_out(p, o, x.dtype), (k, v)
 
 
+@L.named_scope("attn")
 def gqa_decode(cfg, p, x, pos, cache, *, window=0, rope=True, positions=None):
     """Single-token attention against a per-slot ring cache {'k','v'}.
 
@@ -112,6 +114,7 @@ def gqa_decode(cfg, p, x, pos, cache, *, window=0, rope=True, positions=None):
     return _attn_out(p, o, x.dtype), {"k": k, "v": v}
 
 
+@L.named_scope("attn")
 def cross_full(cfg, p, x, enc_out):
     """Cross attention (whisper decoder): q from x, kv from encoder output."""
     B, S, _ = x.shape
@@ -129,6 +132,7 @@ def cross_full(cfg, p, x, enc_out):
     return _attn_out(p, o.reshape(B, S, cfg.num_heads, Dh), x.dtype), (k, v)
 
 
+@L.named_scope("attn")
 def cross_decode(cfg, p, x, cache):
     B = x.shape[0]
     Hkv, G, Dh = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
@@ -143,6 +147,7 @@ def cross_decode(cfg, p, x, cache):
 
 # --- MLA (deepseek) -------------------------------------------------------
 
+@L.named_scope("attn")
 def mla_full(cfg, p, x, positions):
     """Expanded-form MLA for train/prefill; returns compressed cache parts."""
     B, S, _ = x.shape
@@ -171,6 +176,7 @@ def mla_full(cfg, p, x, positions):
     return _attn_out(p, o, x.dtype), (ckv, k_rope[:, :, 0, :])
 
 
+@L.named_scope("attn")
 def mla_decode(cfg, p, x, pos, cache):
     """Absorbed-form MLA decode on the compressed (c_kv, k_rope) cache."""
     B = x.shape[0]
@@ -210,6 +216,7 @@ def mla_decode(cfg, p, x, pos, cache):
 # Block dispatch — full-sequence mode
 # ==========================================================================
 
+@L.named_scope()
 def apply_block_full(cfg, kind, p, h, aux, collect_cache):
     """Returns (h, cache_out_or_None, aux_loss)."""
     h = shard(h, ("batch", "seq_sp", "embed"))   # Megatron-SP residual stream
@@ -337,6 +344,7 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
 # Block dispatch — decode mode
 # ==========================================================================
 
+@L.named_scope()
 def apply_block_decode(cfg, kind, p, h, cache, aux):
     """Returns (h, new_cache)."""
     pos = aux["pos"]
@@ -433,6 +441,7 @@ class Model:
         return init_params(self.cfg, rng)
 
     # ---- embedding / head ----
+    @L.named_scope("embed")
     def _embed(self, params, tokens, positions, batch):
         cfg = self.cfg
         h = jnp.take(params["embed"]["w"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
@@ -446,6 +455,7 @@ class Model:
             h = jax.lax.dynamic_update_slice(h, pe, (0, 0, 0))
         return shard(h, ("batch", "seq_sp", "embed"))
 
+    @L.named_scope("head")
     def _logits(self, params, h):
         cfg = self.cfg
         w = params["embed"]["w"].T if cfg.tie_embeddings else params["lm_head"]["w"]

@@ -11,6 +11,17 @@ Trace replay passes a :class:`~repro.serving.sim.workload.VirtualClock`
 driven in simulated seconds, so caller-supplied ``arrival_s`` values —
 including ``0.0`` — are honored exactly and TTFT/finish times stay on the
 trace's timebase instead of mixing in ``perf_counter`` readings.
+
+On the device path the engine marks its own work on the profiler's
+timeline with ``jax.profiler.TraceAnnotation`` spans, which cost about a
+microsecond each when no profiler runs.  Per admitted request:
+``engine.admit`` (args ``rid``, ``slot``, ``prompt_len``, ``queued``: the
+requests still waiting) holding, in order, ``engine.prefill`` (the eager
+prefill: lowering, compile-cache load, dispatch), ``engine.first_token``
+(the host read of its first token, the TTFT stamp) and ``engine.scatter``
+(its cache rows into the slot).  Per decode step: ``engine.decode`` (arg
+``active``) holding ``engine.sample`` (the argmax and the host read of each
+slot's token).  ``docs/observability.md`` says how to capture them.
 """
 from __future__ import annotations
 
@@ -20,6 +31,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import Model, zero_cache
@@ -31,9 +43,11 @@ class Request:
     prompt: list[int]
     max_new_tokens: int = 16
     arrival_s: float | None = None   # None: stamped by the engine's clock
-    # outputs
+    # outputs, stamped on the engine's clock
     tokens: list[int] = field(default_factory=list)
-    ttft_s: float | None = None
+    start_s: float | None = None     # its admission began (SimRequest.start_s)
+    token_s: list[float] = field(default_factory=list)   # one per token
+    ttft_s: float | None = None      # token_s[0] - arrival_s
     finished_s: float | None = None
     slot: int | None = None
 
@@ -72,23 +86,35 @@ class ServingEngine:
                 break
             req = self.queue.pop(0)
             req.slot = slot
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, pc = self.model.prefill(self.params, {"tokens": prompt},
-                                            cache_len=self.cache_len)
-            tok = int(jnp.argmax(logits[0, -1]))
-            req.tokens.append(tok)
-            req.ttft_s = self.clock() - req.arrival_s
-            # scatter the single-request (batch=1) cache into this slot
-            # (cycle leaves are layer-stacked: batch is dim 1; tail: dim 0)
-            self.cache["blocks"]["cycle"] = jax.tree.map(
-                lambda c, o: c.at[:, slot].set(o[:, 0]) if c.ndim >= 2 else c,
-                self.cache["blocks"]["cycle"], pc["blocks"]["cycle"])
-            self.cache["blocks"]["tail"] = jax.tree.map(
-                lambda c, o: c.at[slot].set(o[0]) if c.ndim >= 1 else c,
-                self.cache["blocks"]["tail"], pc["blocks"]["tail"])
-            self.cache["pos"] = self.cache["pos"].at[slot].set(len(req.prompt))
-            self._last_tok = self._last_tok.at[slot, 0].set(tok)
+            req.start_s = self.clock()
+            with TraceAnnotation("engine.admit", rid=req.rid, slot=slot,
+                                 prompt_len=len(req.prompt), queued=len(self.queue)):
+                with TraceAnnotation("engine.prefill", rid=req.rid):
+                    prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                    logits, pc = self.model.prefill(self.params, {"tokens": prompt},
+                                                    cache_len=self.cache_len)
+                with TraceAnnotation("engine.first_token", rid=req.rid):
+                    tok = int(jnp.argmax(logits[0, -1]))
+                    now = self.clock()
+                    req.tokens.append(tok)
+                    req.token_s.append(now)
+                    req.ttft_s = now - req.arrival_s
+                with TraceAnnotation("engine.scatter", rid=req.rid):
+                    self._scatter(slot, pc, len(req.prompt), tok)
             self.active[slot] = req
+
+    def _scatter(self, slot: int, pc: dict, pos: int, tok: int):
+        """The single-request (batch=1) cache into ``slot``, its position and
+        its last token (cycle leaves are layer-stacked: batch is dim 1;
+        tail: dim 0)."""
+        self.cache["blocks"]["cycle"] = jax.tree.map(
+            lambda c, o: c.at[:, slot].set(o[:, 0]) if c.ndim >= 2 else c,
+            self.cache["blocks"]["cycle"], pc["blocks"]["cycle"])
+        self.cache["blocks"]["tail"] = jax.tree.map(
+            lambda c, o: c.at[slot].set(o[0]) if c.ndim >= 1 else c,
+            self.cache["blocks"]["tail"], pc["blocks"]["tail"])
+        self.cache["pos"] = self.cache["pos"].at[slot].set(pos)
+        self._last_tok = self._last_tok.at[slot, 0].set(tok)
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -96,18 +122,23 @@ class ServingEngine:
         self._admit()
         if not self.active:
             return 0
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          {"tokens": self._last_tok})
-        next_tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        self._last_tok = next_tok[:, None]
-        done = []
-        for slot, req in self.active.items():
-            req.tokens.append(int(next_tok[slot]))
-            if len(req.tokens) >= req.max_new_tokens:
-                req.finished_s = self.clock()
-                done.append(slot)
-        for slot in done:
-            self.finished.append(self.active.pop(slot))
+        with TraceAnnotation("engine.decode", active=len(self.active)):
+            logits, self.cache = self._decode(self.params, self.cache,
+                                              {"tokens": self._last_tok})
+            with TraceAnnotation("engine.sample"):
+                next_tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                self._last_tok = next_tok[:, None]
+                toks = {slot: int(next_tok[slot]) for slot in self.active}
+            now = self.clock()
+            done = []
+            for slot, req in self.active.items():
+                req.tokens.append(toks[slot])
+                req.token_s.append(now)
+                if len(req.tokens) >= req.max_new_tokens:
+                    req.finished_s = now
+                    done.append(slot)
+            for slot in done:
+                self.finished.append(self.active.pop(slot))
         return len(self.active) + len(done)
 
     def run_until_drained(self, max_steps: int = 10_000) -> list[Request]:

@@ -140,7 +140,8 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, optimizer: Optimizer):
             m0 = jax.tree.map(jnp.float32, m0)
             (grads, metrics), _ = jax.lax.scan(acc_step, (g0, m0), micro)
         grads = maybe_compress(grads, run.grad_compression)
-        new_params, new_opt = optimizer.update(grads, state["opt"], params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"], params)
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         metrics = dict(metrics)
         metrics["grad_norm"] = jnp.sqrt(sum(
