@@ -16,15 +16,19 @@ On the device path the engine marks its own work on the profiler's
 timeline with ``jax.profiler.TraceAnnotation`` spans, which cost about a
 microsecond each when no profiler runs.  Per admitted request:
 ``engine.admit`` (args ``rid``, ``slot``, ``prompt_len``, ``queued``: the
-requests still waiting) holding, in order, ``engine.prefill`` (the eager
-prefill: lowering, compile-cache load, dispatch), ``engine.first_token``
-(the host read of its first token, the TTFT stamp) and ``engine.scatter``
-(its cache rows into the slot).  Per decode step: ``engine.decode`` (arg
-``active``) holding ``engine.sample`` (the argmax and the host read of each
-slot's token).  ``docs/observability.md`` says how to capture them.
+requests still waiting) holding, in order, ``engine.prefill`` (args ``rid``
+and ``compiled``: the dispatch of the jitted batch-1 prefill, and, where
+``compiled`` is true, its one compile for a prompt length the engine had
+not prefilled before), ``engine.first_token`` (the wait for the prefill's
+device work and the host read of its first token, the TTFT stamp) and
+``engine.scatter`` (its cache rows into the slot).  Per decode step:
+``engine.decode`` (arg ``active``) holding ``engine.sample`` (the argmax and
+the host read of each slot's token).  ``docs/observability.md`` says how to
+capture them.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable
@@ -68,6 +72,10 @@ class ServingEngine:
         self.queue: list[Request] = []
         self.greedy = greedy
         self._decode = jax.jit(self.model.decode_step)
+        # one program per prompt length, kept by jit's cache (keyed by shape)
+        self._prefill = jax.jit(functools.partial(self.model.prefill,
+                                                  cache_len=cache_len))
+        self._prefilled: set[int] = set()        # prompt lengths seen
         self._last_tok = jnp.zeros((slots, 1), jnp.int32)
         self.finished: list[Request] = []
 
@@ -87,12 +95,14 @@ class ServingEngine:
             req = self.queue.pop(0)
             req.slot = slot
             req.start_s = self.clock()
+            n = len(req.prompt)
             with TraceAnnotation("engine.admit", rid=req.rid, slot=slot,
-                                 prompt_len=len(req.prompt), queued=len(self.queue)):
-                with TraceAnnotation("engine.prefill", rid=req.rid):
+                                 prompt_len=n, queued=len(self.queue)):
+                with TraceAnnotation("engine.prefill", rid=req.rid,
+                                     compiled=n not in self._prefilled):
+                    self._prefilled.add(n)
                     prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                    logits, pc = self.model.prefill(self.params, {"tokens": prompt},
-                                                    cache_len=self.cache_len)
+                    logits, pc = self._prefill(self.params, {"tokens": prompt})
                 with TraceAnnotation("engine.first_token", rid=req.rid):
                     tok = int(jnp.argmax(logits[0, -1]))
                     now = self.clock()
@@ -100,7 +110,7 @@ class ServingEngine:
                     req.token_s.append(now)
                     req.ttft_s = now - req.arrival_s
                 with TraceAnnotation("engine.scatter", rid=req.rid):
-                    self._scatter(slot, pc, len(req.prompt), tok)
+                    self._scatter(slot, pc, n, tok)
             self.active[slot] = req
 
     def _scatter(self, slot: int, pc: dict, pos: int, tok: int):

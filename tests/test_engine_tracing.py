@@ -43,13 +43,19 @@ def serve(tmp_path=None):
         return run(), []
     with jax.profiler.trace(str(tmp_path)):
         done = run()
+    return done, engine_events(tmp_path)
+
+
+def engine_events(tmp_path):
+    """The engine.* host events of the trace written under ``tmp_path``, as
+    (name, start_ns, end_ns, stats) sorted by start."""
     from jax.profiler import ProfileData
     path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True))[-1]
     events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
               for plane in ProfileData.from_file(path).planes
               if not plane.name.startswith("/device")
               for line in plane.lines for e in line.events if e.name.startswith("engine.")]
-    return done, sorted(events, key=lambda ev: ev[1])
+    return sorted(events, key=lambda ev: ev[1])
 
 
 @pytest.fixture(scope="module")
@@ -106,6 +112,40 @@ def test_served_tokens_do_not_depend_on_the_profiler(traced):
     done, _ = traced
     plain, _ = serve()
     assert {k: r.tokens for k, r in plain.items()} == {k: r.tokens for k, r in done.items()}
+
+
+def test_prefill_compiles_once_per_prompt_length(tmp_path):
+    """Two prompts of one length, then one of another, each admitted in a
+    step of its own: the second admission of a seen length compiles no
+    program, and each ``engine.prefill`` span says whether its admission
+    met a new length."""
+    cfg = get_tiny_config("phi4-mini-3.8b")
+    params = Model(cfg).init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, slots=3, cache_len=32)
+    compiled = []
+
+    def listen(name, secs, **_kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiled.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    per_step = []
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            for rid, prompt in enumerate([[1, 2, 3, 4], [5, 6, 7, 8], [9, 8, 7]]):
+                eng.submit(Request(rid=rid, prompt=prompt, max_new_tokens=4))
+                before = len(compiled)
+                eng.step()
+                assert eng.active and max(r.rid for r in eng.active.values()) == rid
+                per_step.append(len(compiled) - before)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    # the first step compiles the prefill and the decode step, the second
+    # (same length, another slot) nothing, the third a prefill for length 3
+    assert per_step[0] > 0 and per_step[1] == 0 and per_step[2] > 0
+    prefills = [ev for ev in engine_events(tmp_path) if ev[0] == "engine.prefill"]
+    assert [p[3]["rid"] for p in prefills] == [0, 1, 2]
+    assert [bool(p[3]["compiled"]) for p in prefills] == [True, False, True]
 
 
 def op_names(fn, *args) -> set:
