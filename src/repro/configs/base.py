@@ -36,6 +36,12 @@ class ModelConfig:
     rope_style: str = "standard"     # standard | mrope | partial | none
     rope_fraction: float = 1.0       # fraction of head_dim rotated (phi4 partial rope)
     rope_theta: float = 10_000.0
+    # YaRN (DeepSeek-V3's ``rope_scaling``); factor 0 = plain RoPE
+    yarn_factor: float = 0.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0     # softmax scale times mscale(factor, this)^2
+    yarn_original_max_pos: int = 4096
     window: int = 0                  # sliding-window size (0 = full attention)
     logit_soft_cap: float = 0.0
     attn_score_dtype: str = "float32"   # bfloat16: flash-style low-prec P*V path
@@ -52,12 +58,24 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0             # the router's width: every expert it scores
     top_k: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0                # per-expert hidden size
-    capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # router: softmax (top-k of a softmax) | noaux_tc (DeepSeek-V3: sigmoid
+    # scores, a per-expert correction bias that picks but does not weigh,
+    # top ``topk_group`` of ``n_group`` groups, then top-k); the k weights
+    # are normalised, then scaled by ``routed_scaling_factor``
+    router: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # this device's share of the experts: ``num_experts_held`` of them from
+    # ``expert_offset`` (0 -> all); the router still scores ``num_experts``
+    num_experts_held: int = 0
+    expert_offset: int = 0
+    first_k_dense: int = 0           # leading dense layers (width d_ff) before the MoE ones
 
     # --- MLA (deepseek) ---
     q_lora_rank: int = 0
@@ -92,6 +110,10 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // max(self.num_heads, 1))
+        if self.expert_offset + self.held_experts > self.num_experts:
+            raise ValueError(f"{self.name}: experts {self.expert_offset}.."
+                             f"{self.expert_offset + self.held_experts - 1} outside the "
+                             f"router's {self.num_experts}")
 
     @property
     def q_per_kv(self) -> int:
@@ -100,6 +122,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """How many experts this device holds and computes."""
+        return self.num_experts_held or self.num_experts
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -19,7 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.core import tracer
 from repro.core.ir import Graph
 from repro.core.stubs import ingest_attention
-from repro.models import abstract_params, block_cycle
+from repro.models import abstract_params, block_cycle, lead_blocks
 from repro.models.kvcache import build_cache
 from repro.models.model import Model, apply_block_decode, apply_block_full
 
@@ -278,17 +278,21 @@ def ingest_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
     return mg
 
 
-def _cycle_param_slice(cfg: ModelConfig, pos: int):
-    """Abstract params of one layer at cycle position ``pos``."""
-    pa = abstract_params(cfg)
-    stacked = pa["blocks"]["cycle"][pos]
-    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype), stacked)
+def _layer_slice(tree: dict, group: str, pos: int):
+    """One layer at ``pos`` of a ``blocks`` group of an abstract params or
+    cache tree; ``cycle`` leaves drop their stacked layer dim."""
+    one = tree["blocks"][group][pos]
+    if group != "cycle":
+        return one
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype), one)
 
 
 def _tag_moe(g: Graph, cfg: ModelConfig) -> Graph:
+    """Mark the experts' grouped matmuls (one row per routed pair) for
+    expert parallelism."""
     if cfg.num_experts:
         for n in g:
-            if n.kind == "matmul" and n.out_shape and n.out_shape[0] == cfg.num_experts:
+            if n.kind == "matmul" and n.attrs.get("ragged"):
                 n.attrs["moe_expert"] = True
     return g
 
@@ -297,11 +301,11 @@ def block_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
                  *, cache_len: int = 0) -> ModelGraphs:
     """Trace one graph per distinct block kind (+ embed/head)."""
     cycle, n_cycles, tail = block_cycle(cfg)
-    counts: dict[int, int] = {}
-    kinds: dict[int, str] = {}
-    for j, k in enumerate(cycle):
-        counts[j] = n_cycles + (1 if j < len(tail) else 0)
-        kinds[j] = k
+    # (kind, params group, position, layers of it): the lead, then the cycle
+    # with the tail counted in
+    layers = [(k, "lead", j, 1) for j, k in enumerate(lead_blocks(cfg))]
+    layers += [(k, "cycle", j, n_cycles + (1 if j < len(tail) else 0))
+               for j, k in enumerate(cycle)]
     dt = jnp.dtype(cfg.dtype)
     D = cfg.d_model
     x_abs = jax.ShapeDtypeStruct((B_local, S, D), dt)
@@ -313,17 +317,15 @@ def block_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
     blocks: list[BlockGraphs] = []
     with ingest_attention():
         seen_kinds: dict[str, BlockGraphs] = {}
-        for j, kind in kinds.items():
+        for kind, group, j, repeat in layers:
             if kind in seen_kinds:
-                seen_kinds[kind].repeat += counts[j]
+                seen_kinds[kind].repeat += repeat
                 continue
-            p_abs = _cycle_param_slice(cfg, j)
+            p_abs = _layer_slice(abstract_params(cfg), group, j)
             if mode == "decode":
-                cache_stacked = build_cache(
+                cache_abs = _layer_slice(build_cache(
                     cfg, lambda s, l, d: jax.ShapeDtypeStruct(s, d), B_local,
-                    cache_len or S)["blocks"]["cycle"][j]
-                cache_abs = jax.tree.map(
-                    lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype), cache_stacked)
+                    cache_len or S), group, j)
                 x1 = jax.ShapeDtypeStruct((B_local, 1, D), dt)
                 posv = jax.ShapeDtypeStruct((B_local,), jnp.int32)
 
@@ -334,7 +336,7 @@ def block_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
 
                 fwd = _tag_moe(tracer.trace(dec_fn, p_abs, x1, cache_abs, posv,
                                             name=f"{kind}.decode"), cfg)
-                bg = BlockGraphs(kind, counts[j], fwd)
+                bg = BlockGraphs(kind, repeat, fwd)
             else:
                 def fwd_fn(p, x, positions, enc=None, _kind=kind):
                     aux = {"positions": positions, "cache_len": 0}
@@ -349,10 +351,10 @@ def block_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
                         lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.fwd"), cfg)
                     joint = _tag_moe(tracer.trace_grad(
                         lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.joint"), cfg)
-                    bg = BlockGraphs(kind, counts[j], fwd, joint)
+                    bg = BlockGraphs(kind, repeat, fwd, joint)
                 else:
                     fwd = _tag_moe(tracer.trace(fwd_fn, *args, name=f"{kind}.fwd"), cfg)
-                    bg = BlockGraphs(kind, counts[j], fwd)
+                    bg = BlockGraphs(kind, repeat, fwd)
             seen_kinds[kind] = bg
             blocks.append(bg)
 

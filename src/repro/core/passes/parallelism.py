@@ -127,8 +127,8 @@ class SequenceParallelPass:
 
 
 class ExpertParallelPass:
-    """EP: expert GEMMs shard over ep; an all-to-all pair moves capacity rows
-    to expert owners and back (Megatron/DeepSpeed-MoE dataflow)."""
+    """EP: expert GEMMs shard over ep; an all-to-all pair moves the routed
+    rows to expert owners and back (Megatron/DeepSpeed-MoE dataflow)."""
 
     name = "ep"
 
@@ -153,10 +153,12 @@ class ExpertParallelPass:
                 and n.out_shape[0] == self.num_experts)
             if is_expert:
                 if not expert_nodes:  # first expert GEMM: dispatch all-to-all
+                    # the rows it multiplies move, not the expert weights
+                    rows = n.attrs.get("mm_bytes", (n.bytes_in,))[0]
                     a2a = out.op("all_to_all", deps=list(n.deps),
-                                 comm_bytes=n.bytes_in, comm_group="ep",
-                                 comm_size=ep, bytes_in=n.bytes_in,
-                                 bytes_out=n.bytes_in, repeat=n.repeat,
+                                 comm_bytes=rows, comm_group="ep",
+                                 comm_size=ep, bytes_in=rows,
+                                 bytes_out=rows, repeat=n.repeat,
                                  phase=n.phase, dtype=n.dtype)
                     n.deps = [a2a.name]
                 n.flops /= ep

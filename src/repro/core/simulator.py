@@ -245,7 +245,7 @@ class Simulator:
         if par.sp > 1:
             pm.add(SequenceParallelPass())
         if cfg.num_experts:
-            pm.add(ExpertParallelPass(cfg.num_experts))
+            pm.add(ExpertParallelPass(cfg.held_experts))
         if fusion:
             pm.add(FusionPass())
         if quantize:

@@ -85,8 +85,16 @@ class _TraceCtx:
 
 
 def _dot_general_node(ctx: _TraceCtx, eqn, mult: float, phase: str):
-    dn = eqn.params["dimension_numbers"]
-    (lc, rc), (lb, rb) = dn
+    """A matmul node.  ``ragged_dot_general`` (the experts' grouped matmul)
+    counts its lhs rows once, each against its own group's matrix; the
+    node carries ``ragged``."""
+    ragged = "ragged_dot_dimension_numbers" in eqn.params
+    if ragged:
+        rdn = eqn.params["ragged_dot_dimension_numbers"]
+        (lc, rc), (lb, rb) = rdn.dot_dimension_numbers
+        rb = tuple(rb) + tuple(rdn.rhs_group_dimensions)
+    else:
+        (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
     lhs, rhs = eqn.invars[0].aval, eqn.invars[1].aval
     out = eqn.outvars[0].aval
     contract = 1
@@ -94,6 +102,10 @@ def _dot_general_node(ctx: _TraceCtx, eqn, mult: float, phase: str):
         contract *= lhs.shape[d]
     out_elems = _aval_elems(out)
     flops = 2.0 * out_elems * contract
+    if ragged and set(rdn.lhs_ragged_dimensions) & set(lc):
+        # ragged contraction (the experts' weight gradient): the output has
+        # a block per group, each summing over that group's rows only
+        flops /= eqn.invars[2].aval.shape[-1]
     # (M, N, K) for the MXU-alignment model
     n = 1
     for d in range(len(rhs.shape)):
@@ -110,6 +122,8 @@ def _dot_general_node(ctx: _TraceCtx, eqn, mult: float, phase: str):
         attrs={"mm_dims": (int(m), int(n), int(contract)),
                "mm_bytes": (_aval_bytes(lhs), _aval_bytes(rhs))},
     )
+    if ragged:
+        node.attrs["ragged"] = True
     return node
 
 
@@ -174,7 +188,7 @@ def _trace_jaxpr(ctx: _TraceCtx, jaxpr, mult: float, phase: str):
             node.attrs["attn_dims"] = (int(b), int(hkv * g_), int(sq),
                                        int(v.shape[1]), int(dq))
             node.attrs["causal"], node.attrs["window"] = causal, window
-        elif prim == "dot_general":
+        elif prim in ("dot_general", "ragged_dot_general"):
             node = _dot_general_node(ctx, eqn, mult, phase)
         elif prim in ("conv_general_dilated",):
             out_elems = _aval_elems(out)

@@ -17,25 +17,28 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool = False):
-    """q: (B,H,Sq,D); k/v: (B,Hkv,Sk,D)."""
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
+                    scale: float | None = None, interpret: bool = False):
+    """q: (B,H,Sq,D); k: (B,Hkv,Sk,D); v: (B,Hkv,Sk,Dv)."""
+    return _fa.flash_attention(q, k, v, causal=causal, window=window, scale=scale,
                                interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale", "block_q",
+                                             "block_k", "interpret"))
 def flash_attention_bshd(q, k, v, *, causal: bool = True, window: int = 0,
-                         interpret: bool = False):
-    """Model layout: q (B,S,Hkv,G,D); k/v (B,T,Hkv,D) -> (B,S,Hkv,G,D)."""
+                         scale: float | None = None, block_q: int = _fa.DEFAULT_BLOCK_Q,
+                         block_k: int = _fa.DEFAULT_BLOCK_K, interpret: bool = False):
+    """Model layout: q (B,S,Hkv,G,D); k (B,T,Hkv,D); v (B,T,Hkv,Dv)
+    -> (B,S,Hkv,G,Dv)."""
     B, S, Hkv, G, D = q.shape
     qh = q.reshape(B, S, Hkv * G, D).transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
-    o = _fa.flash_attention(qh, kh, vh, causal=causal, window=window,
-                            interpret=interpret)
-    return o.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
+    o = _fa.flash_attention(qh, kh, vh, causal=causal, window=window, scale=scale,
+                            block_q=block_q, block_k=block_k, interpret=interpret)
+    return o.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, v.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1,7 +1,8 @@
 """Decode-cache construction: concrete zeros, abstract specs, and shardings.
 
 Cache layout mirrors ``params['blocks']`` (stacked over cycle repetitions so
-``decode_step`` can scan over depth) plus a global ``pos`` scalar.
+``decode_step`` can scan over depth; the lead and the tail unstacked) plus a
+per-slot ``pos``.
 
 KV sharding policy (divisibility-aware, see DESIGN.md):
   * kv_heads % model-axis == 0  -> heads sharded (Megatron TP decode)
@@ -18,7 +19,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import axis_size
-from repro.models.params import block_cycle
+from repro.models.params import block_cycle, lead_blocks
 
 CacheCreator = Callable[..., object]  # creator(shape, logical, dtype) -> leaf
 
@@ -38,7 +39,7 @@ def _kind_cache(cfg: ModelConfig, kind: str, c: CacheCreator, batch: int, cache_
         return kv(cache_len)
     if kind == "griffin_attn":
         return kv(min(cache_len, cfg.window) if cfg.window else cache_len)
-    if kind == "mla_moe":
+    if kind in ("mla_moe", "mla_ffn"):
         return {"ckv": c((batch, cache_len, cfg.kv_lora_rank), ("batch", "kv_seq", None), dt),
                 "kr": c((batch, cache_len, cfg.qk_rope_head_dim), ("batch", "kv_seq", None), dt)}
     if kind == "griffin_rec":
@@ -67,12 +68,15 @@ def _kind_cache(cfg: ModelConfig, kind: str, c: CacheCreator, batch: int, cache_
 
 def build_cache(cfg: ModelConfig, creator: CacheCreator, batch: int, cache_len: int):
     cycle, n, tail = block_cycle(cfg)
+    lead = lead_blocks(cfg)
 
     def stacked(shape, logical, dtype):
         return creator((n, *shape), ("layer", *logical), dtype)
 
     return {
         "blocks": {
+            **({"lead": [_kind_cache(cfg, k, creator, batch, cache_len) for k in lead]}
+               if lead else {}),
             "cycle": [_kind_cache(cfg, k, stacked, batch, cache_len) for k in cycle],
             "tail": [_kind_cache(cfg, k, creator, batch, cache_len) for k in tail],
         },

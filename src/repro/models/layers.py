@@ -9,7 +9,7 @@ Attention has three execution strategies:
   * ``dense``     — plain einsum softmax attention (small sequences, tests)
   * ``blockwise`` — lax.scan online-softmax attention (memory-safe at 32k+;
                     the XLA analogue of the Pallas flash kernel)
-  * ``pallas``    — repro.kernels flash attention (TPU runtime target)
+  * ``pallas``    — repro.kernels flash attention (TPU only; MLA prefill)
 """
 from __future__ import annotations
 
@@ -60,6 +60,41 @@ def _rope_freqs(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's magnitude correction, 0.1 * mscale * ln(scale) + 1 (1 at
+    scale <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_freqs(cfg, dim: int) -> jax.Array:
+    """YaRN inverse frequencies (DeepSeek-V3's ``rope_scaling`` "yarn"):
+    pairs that turn more than ``beta_fast`` times over the original context
+    keep their frequency, those that turn fewer than ``beta_slow`` times are
+    divided by ``factor``, with a linear ramp between.  YaRN's factor on cos
+    and sin, mscale(factor, mscale) / mscale(factor, mscale_all_dim), is 1
+    where the two mscales are equal, as DeepSeek-V3 publishes them."""
+    base, factor, orig = cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_max_pos
+
+    def dim_of(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    extra = _rope_freqs(dim, base)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def attn_scale(cfg) -> float:
+    """Factor on the softmax scale: YaRN's mscale(factor, mscale_all_dim)^2,
+    1 without YaRN."""
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
+
+
 def _rotate(x: jax.Array, angles: jax.Array) -> jax.Array:
     """x: (..., D_rot) with angles (..., D_rot/2)."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -76,7 +111,7 @@ def apply_rope(cfg, x: jax.Array, positions: jax.Array) -> jax.Array:
     rot -= rot % 2
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     half = rot // 2
-    inv = _rope_freqs(rot, cfg.rope_theta)  # (half,)
+    inv = _yarn_freqs(cfg, rot) if cfg.yarn_factor else _rope_freqs(rot, cfg.rope_theta)
     if cfg.rope_style == "mrope":
         # 3-section rotary (t, h, w): split the half-dim 1/4, 3/8, 3/8
         # (Qwen2-VL mrope_section, e.g. [16, 24, 24] for half=64).
@@ -238,10 +273,18 @@ def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
 def attention(q, k, v, *, q_offset=0, causal=True, window=0, kv_valid_len=None,
               soft_cap=0.0, strategy="auto", scale=None,
               q_block=2048, kv_block=512, score_dtype=jnp.float32):
-    """Dispatch over attention strategies.  Shapes as in :func:`attend_dense`."""
+    """Dispatch over attention strategies.  Shapes as in :func:`attend_dense`.
+    ``pallas`` (the flash kernel, TPU only: one device, self-attention, no
+    valid length or soft cap) takes ``q_block`` and ``kv_block`` as its
+    tiles."""
     T = k.shape[1]
     if strategy == "auto":
         strategy = "blockwise" if (q.shape[1] * T > 2048 * 2048 or T > 1024) else "dense"
+    if strategy == "pallas":
+        from repro.kernels import ops
+        assert q_offset == 0 and kv_valid_len is None and not soft_cap
+        return ops.flash_attention_bshd(q, k, v, causal=causal, window=window, scale=scale,
+                                        block_q=q_block, block_k=kv_block)
     if strategy == "blockwise":
         return attend_blockwise(q, k, v, q_offset=q_offset, causal=causal, window=window,
                                 kv_valid_len=kv_valid_len, soft_cap=soft_cap, scale=scale,
@@ -292,122 +335,148 @@ def ffn(cfg, p: dict, x: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# Mixture of Experts (capacity-factor, sort-based dispatch)
+# Mixture of Experts (dropless; this device's share of the experts)
 # --------------------------------------------------------------------------
 
-def _moe_dispatch(cfg, xf: jax.Array, router_w: jax.Array, cap: int):
-    """Local sort-based top-k dispatch.  xf: (T, D) -> buf (E, cap, D) plus
-    combine metadata and the Switch load-balancing aux loss."""
-    T, D = xf.shape
+def _route(cfg, xf: jax.Array, router: dict):
+    """Top-k routing over all ``num_experts`` experts.  xf: (T, D) ->
+    expert ids (T, K), their weights (T, K) in float32, and the Switch
+    load-balancing aux loss (0 for noaux_tc, which balances by its bias).
+
+    ``softmax``: top-k of the softmax.  ``noaux_tc`` (DeepSeek-V3): sigmoid
+    scores; the correction bias is added for the choice only; each group of
+    experts is scored by the sum of its two best, the tokens keep the
+    ``topk_group`` best groups and take their top-k there; the weights are
+    the chosen sigmoid scores.  Both normalise the k weights to sum to 1
+    and scale them by ``routed_scaling_factor``.
+    """
+    T = xf.shape[0]
     E, K = cfg.num_experts, cfg.top_k
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, ids = jax.lax.top_k(probs, K)                        # (T, K)
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router["w"].astype(jnp.float32))
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.router == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, ids = jax.lax.top_k(probs, K)                     # (T, K)
+        onehot = jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32)
+        aux = E * jnp.mean(jnp.mean(onehot, 0) * jnp.mean(probs, 0)) * cfg.router_aux_coef
+    elif cfg.router == "noaux_tc":
+        scores = jax.nn.sigmoid(logits)
+        pick = scores + router["bias"].astype(jnp.float32)
+        if cfg.n_group > 1:
+            G = cfg.n_group
+            grouped = pick.reshape(T, G, E // G)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)              # (T, G)
+            top_groups = jax.lax.top_k(group_score, cfg.topk_group)[1]
+            keep = jax.nn.one_hot(top_groups, G, dtype=jnp.float32).sum(1) > 0
+            pick = jnp.where(jnp.repeat(keep, E // G, axis=1), pick, -jnp.inf)
+        ids = jax.lax.top_k(pick, K)[1]
+        gate_vals = jnp.take_along_axis(scores, ids, axis=-1)
+    else:
+        raise ValueError(f"unknown router {cfg.router!r}")
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    onehot = jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32)
-    aux = E * jnp.mean(jnp.mean(onehot, 0) * jnp.mean(probs, 0)) * cfg.router_aux_coef
-
-    flat_ids = ids.reshape(-1)                                      # (T*K,)
-    order = jnp.argsort(flat_ids, stable=True)
-    sorted_ids = flat_ids[order]
-    seg_start = jnp.searchsorted(sorted_ids, sorted_ids, side="left")
-    pos = jnp.arange(T * K) - seg_start                             # position within expert
-    keep = pos < cap
-    xs = xf[order // K]
-    buf = jnp.zeros((E, cap, D), xf.dtype)
-    buf = buf.at[sorted_ids, jnp.where(keep, pos, cap)].set(
-        jnp.where(keep[:, None], xs, 0), mode="drop")
-    return buf, (order, sorted_ids, pos, keep, gate_vals), aux
+    return ids, gate_vals * cfg.routed_scaling_factor, aux
 
 
-def _moe_combine(eo: jax.Array, meta, T: int, K: int, dtype):
-    order, sorted_ids, pos, keep, gate_vals = meta
-    D = eo.shape[-1]
-    back = eo[sorted_ids, jnp.where(keep, pos, 0)] * keep[:, None].astype(eo.dtype)
-    unsorted = jnp.zeros_like(back).at[order].set(back)             # (T*K, D)
-    return (unsorted.reshape(T, K, D) * gate_vals[..., None].astype(eo.dtype)).sum(1).astype(dtype)
-
-
-def _expert_mlp(p: dict, buf: jax.Array, dtype) -> jax.Array:
-    """(E, C, D) x per-expert SwiGLU weights (E, D, F) -> (E, C, D)."""
-    g = jnp.einsum("ecd,edf->ecf", buf, p["gate"].astype(dtype))
-    u = jnp.einsum("ecd,edf->ecf", buf, p["up"].astype(dtype))
-    h = jax.nn.silu(g) * u
-    return jnp.einsum("ecf,efd->ecd", h, p["down"].astype(dtype))
+def _held_experts(xf: jax.Array, ids: jax.Array, gate_vals: jax.Array, w: dict,
+                  offset) -> jax.Array:
+    """The part of the experts in ``w`` (E of them, ids from ``offset``) on
+    xf (T, D), dropless: the (token, expert) pairs are sorted by expert, the
+    held experts' pairs first, and one ragged product per matrix gives each
+    group of rows its expert.  Pairs of experts not held fall past the
+    groups and add nothing.  So the matmuls have ``T * top_k`` rows, one per
+    routed pair.  Returns (T, D) float32."""
+    T, D = xf.shape
+    K, E = ids.shape[1], w["gate"].shape[0]
+    dt = xf.dtype
+    with jax.named_scope("moe_route"):
+        local = ids.reshape(-1) - offset                              # (T*K,)
+        local = jnp.where((local >= 0) & (local < E), local, E)      # E: not held
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.bincount(local, length=E + 1)[:E].astype(jnp.int32)
+        xs = xf[order // K]
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, w["gate"].astype(dt), sizes)
+        u = jax.lax.ragged_dot(xs, w["up"].astype(dt), sizes)
+        eo = jax.lax.ragged_dot(jax.nn.silu(g) * u, w["down"].astype(dt), sizes)
+    with jax.named_scope("moe_route"):
+        eo = jnp.where((local[order] < E)[:, None], eo, 0)
+        back = jnp.zeros_like(eo).at[order].set(eo).reshape(T, K, D)
+        return (back.astype(jnp.float32) * gate_vals[..., None]).sum(1)
 
 
 @named_scope("moe")
 def moe_ffn(cfg, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Top-k routed experts with true expert parallelism.
+    """Routed experts, dropless, over this device's share of them, plus the
+    shared expert.
 
-    GSPMD cannot shard the sort/gather/scatter dispatch (it replicates batched
-    gathers), so the MoE interior runs under ``shard_map``: each device
-    dispatches its local tokens, an ``all_to_all`` over the model axis moves
-    capacity rows to the expert owners (Megatron-EP dataflow), expert GEMMs
-    run on local expert shards, and a second ``all_to_all`` returns outputs.
-    Returns (output, router_aux_loss).
+    The router scores all ``num_experts`` experts and picks each token's
+    top-k among them; this device adds ``weight * expert(x)`` for the
+    chosen experts it holds (``held_experts`` from ``expert_offset``) and
+    leaves out what absent experts would add.  No token is dropped: every
+    routed pair to a held expert is computed, and only those
+    (:func:`_held_experts`).
+
+    On a mesh the held experts are sharded over its ``model`` axis under
+    ``shard_map`` (GSPMD cannot shard the sort and gather): where the tokens
+    are sharded over that axis too, each device gathers them all, computes
+    its experts' pairs and a reduce-scatter sums the parts back onto the
+    tokens' owners; where they are not, a ``psum`` sums the parts.  The
+    buffers stay at a row per routed pair, whatever the routing.
+
+    Scopes: ``moe_route`` (router, group top-k, sort, unsort and combine),
+    ``moe_experts`` (the held experts' matmuls); the shared expert is
+    ``ffn``.  Returns (output, router_aux_loss).
     """
     from repro.distributed.sharding import active_env, resolve_spec
 
     B, S, D = x.shape
-    E, K = cfg.num_experts, cfg.top_k
     env = active_env()
     mesh = env.mesh if env is not None else None
     m = mesh.shape.get("model", 1) if mesh is not None else 1
-    if E % m != 0:
+    if cfg.held_experts % m != 0:
         m = 1  # experts unshardable -> local compute, replicated weights
 
+    def part(xf, router, experts, offset):
+        with jax.named_scope("moe_route"):
+            ids, gate_vals, aux = _route(cfg, xf, router)
+        return _held_experts(xf, ids, gate_vals, experts, offset), aux
+
     if mesh is None or all(s == 1 for s in mesh.shape.values()):
-        # single-device path (tests, CPU examples)
-        xf = x.reshape(B * S, D)
-        cap = max(int(math.ceil(B * S * K / E * cfg.capacity_factor)), 4)
-        buf, meta, aux = _moe_dispatch(cfg, xf, p["router"]["w"], cap)
-        eo = _expert_mlp(p["experts"], buf, x.dtype)
-        out = _moe_combine(eo, meta, B * S, K, x.dtype).reshape(B, S, D)
-        if cfg.num_shared_experts > 0:
-            out = out + ffn(cfg, p["shared"], x)
-        return out, aux
+        out, aux = part(x.reshape(B * S, D), p["router"], p["experts"], cfg.expert_offset)
+        out = out.astype(x.dtype).reshape(B, S, D)
+    else:
+        from jax import shard_map
+        P = jax.sharding.PartitionSpec
+        x_spec = resolve_spec(env, ("batch", "seq_sp", None), x.shape)
+        ew_spec = resolve_spec(env, ("expert", None, None), p["experts"]["gate"].shape)
+        rep = jax.tree.map(lambda _: P(), p["router"])
+        all_axes = tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
+        gather = m > 1 and "model" in jax.tree.leaves(tuple(x_spec))
 
-    from jax import shard_map
-    P = jax.sharding.PartitionSpec
-    x_spec = resolve_spec(env, ("batch", "seq_sp", None), x.shape)
-    ew_spec = resolve_spec(env, ("expert", None, None), p["experts"]["gate"].shape)
-    rw_spec = P()
-    all_axes = tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
+        def body(x_loc, router, experts):
+            b, s, _ = x_loc.shape
+            xf = x_loc.reshape(b * s, D)
+            offset = cfg.expert_offset
+            if m > 1:
+                offset = offset + jax.lax.axis_index("model") * (cfg.held_experts // m)
+            with jax.named_scope("moe_route"):
+                if gather:
+                    xf = jax.lax.all_gather(xf, "model", tiled=True)
+            out, aux = part(xf, router, experts, offset)
+            with jax.named_scope("moe_route"):
+                if gather:
+                    out = jax.lax.psum_scatter(out, "model", tiled=True)
+                elif m > 1:
+                    out = jax.lax.psum(out, "model")
+            aux = jax.lax.pmean(aux, all_axes)
+            return out.astype(x_loc.dtype).reshape(b, s, D), aux
 
-    # local token count per device (static)
-    def _sh(spec_entry):
-        if spec_entry is None:
-            return 1
-        axes = spec_entry if isinstance(spec_entry, tuple) else (spec_entry,)
-        sz = 1
-        for a in axes:
-            sz *= mesh.shape[a]
-        return sz
-    xs_full = list(x_spec) + [None] * (3 - len(list(x_spec)))
-    T_loc = (B // _sh(xs_full[0])) * (S // _sh(xs_full[1]))
-    cap = max(int(math.ceil(T_loc * K / E * cfg.capacity_factor)), 4)
-
-    def body(x_loc, router_w, gate_w, up_w, down_w):
-        b, s, _ = x_loc.shape
-        xf = x_loc.reshape(b * s, D)
-        buf, meta, aux = _moe_dispatch(cfg, xf, router_w, cap)       # (E, cap, D)
-        if m > 1:
-            # EP all-to-all: (E, cap, D) -> (E/m, cap*m, D) on expert owners
-            buf = jax.lax.all_to_all(buf, "model", split_axis=0, concat_axis=1, tiled=True)
-        eo = _expert_mlp({"gate": gate_w, "up": up_w, "down": down_w}, buf, x_loc.dtype)
-        if m > 1:
-            eo = jax.lax.all_to_all(eo, "model", split_axis=1, concat_axis=0, tiled=True)
-        out = _moe_combine(eo, meta, b * s, K, x_loc.dtype).reshape(b, s, D)
-        aux = jax.lax.pmean(aux, all_axes)
-        return out, aux
-
-    out, aux = shard_map(
-        body, mesh=mesh,
-        in_specs=(x_spec, rw_spec, ew_spec, ew_spec, ew_spec),
-        out_specs=(x_spec, P()),
-        check_vma=False,
-    )(x, p["router"]["w"], p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"])
+        out, aux = shard_map(
+            body, mesh=mesh,
+            in_specs=(x_spec, rep, jax.tree.map(lambda _: ew_spec, p["experts"])),
+            out_specs=(x_spec, P()),
+            check_vma=False,
+        )(x, p["router"], p["experts"])
 
     if cfg.num_shared_experts > 0:
         out = out + ffn(cfg, p["shared"], x)

@@ -18,9 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import axis_size, logical_constraint as shard
+from repro.distributed.sharding import active_env, axis_size, logical_constraint as shard
 from repro.models import layers as L
-from repro.models.params import block_cycle, build_params, init_params
+from repro.models.params import block_cycle, build_params, init_params, lead_blocks
 
 Pytree = Any
 
@@ -147,9 +147,13 @@ def cross_decode(cfg, p, x, cache):
 
 # --- MLA (deepseek) -------------------------------------------------------
 
+MLA_Q_BLOCK, MLA_KV_BLOCK = 512, 512     # the flash kernel's tiles at prefill
+
+
 @L.named_scope("attn")
-def mla_full(cfg, p, x, positions):
-    """Expanded-form MLA for train/prefill; returns compressed cache parts."""
+def mla_full(cfg, p, x, positions, *, prefill: bool = False):
+    """Expanded-form MLA for train/prefill; returns compressed cache parts.
+    A ``prefill`` on one TPU attends with the flash kernel (forward only)."""
     B, S, _ = x.shape
     H = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -169,9 +173,14 @@ def mla_full(cfg, p, x, positions):
     q_all = shard(q_all, ("batch", "seq", "heads", None, "head_dim"))
     k_all = shard(k_all, ("batch", "seq", "heads", "head_dim"))
     v = shard(v, ("batch", "seq", "heads", "head_dim"))
+    # 128 heads make the float32 scores' passes through HBM the XLA path's
+    # largest cost at prefill; on one TPU the flash kernel keeps them in VMEM.
+    tiles = {}
+    if prefill and jax.default_backend() == "tpu" and active_env() is None:
+        tiles = dict(strategy="pallas", q_block=MLA_Q_BLOCK, kv_block=MLA_KV_BLOCK)
     o = L.attention(q_all, k_all, v, q_offset=0, causal=True,
-                    scale=1.0 / math.sqrt(dn + dr),
-                    score_dtype=jnp.dtype(cfg.attn_score_dtype))
+                    scale=L.attn_scale(cfg) / math.sqrt(dn + dr),
+                    score_dtype=jnp.dtype(cfg.attn_score_dtype), **tiles)
     o = o.reshape(B, S, H, dv)
     return _attn_out(p, o, x.dtype), (ckv, k_rope[:, :, 0, :])
 
@@ -201,7 +210,7 @@ def mla_decode(cfg, p, x, pos, cache):
     kr = cache["kr"].at[b_idx, slot].set(kr_new[:, 0])
     ckv = shard(ckv, ("batch", "kv_seq", None))
     kr = shard(kr, ("batch", "kv_seq", None))
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = L.attn_scale(cfg) / math.sqrt(dn + dr)
     s = (jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32), ckv.astype(jnp.float32))
          + jnp.einsum("bshk,btk->bhst", q_rope.astype(jnp.float32), kr.astype(jnp.float32))) * scale
     valid = jnp.minimum(pos + 1, T)
@@ -244,26 +253,30 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, kv_cache(k, v), zero
 
-    if kind in ("moe_attn_ffn", "mla_moe"):
+    if kind in ("moe_attn_ffn", "mla_moe", "mla_ffn"):
         y = L.apply_norm(cfg, p["ln1"], h)
-        if kind == "mla_moe":
-            a, (ckv, kr) = mla_full(cfg, p["attn"], y, positions)
-        else:
+        if kind == "moe_attn_ffn":
             a, (k, v) = gqa_full(cfg, p["attn"], y, positions)
+        else:
+            a, (ckv, kr) = mla_full(cfg, p["attn"], y, positions, prefill=collect_cache)
         h = h + a
-        m, aux_loss = L.moe_ffn(cfg, p["moe"], L.apply_norm(cfg, p["ln2"], h))
+        y = L.apply_norm(cfg, p["ln2"], h)
+        if kind == "mla_ffn":
+            m, aux_loss = L.ffn(cfg, p["mlp"], y), zero
+        else:
+            m, aux_loss = L.moe_ffn(cfg, p["moe"], y)
         h = h + m
-        if kind == "mla_moe":
-            cache = None
-            if collect_cache:
-                T = cache_len
-                ckv_c = jnp.zeros((ckv.shape[0], T, ckv.shape[2]), ckv.dtype)
-                kr_c = jnp.zeros((kr.shape[0], T, kr.shape[2]), kr.dtype)
-                ckv_c = jax.lax.dynamic_update_slice(ckv_c, ckv, (0, 0, 0))
-                kr_c = jax.lax.dynamic_update_slice(kr_c, kr, (0, 0, 0))
-                cache = {"ckv": ckv_c, "kr": kr_c}
-            return h, cache, aux_loss
-        return h, kv_cache(k, v), aux_loss
+        if kind == "moe_attn_ffn":
+            return h, kv_cache(k, v), aux_loss
+        cache = None
+        if collect_cache:
+            T = cache_len
+            ckv_c = jnp.zeros((ckv.shape[0], T, ckv.shape[2]), ckv.dtype)
+            kr_c = jnp.zeros((kr.shape[0], T, kr.shape[2]), kr.dtype)
+            ckv_c = jax.lax.dynamic_update_slice(ckv_c, ckv, (0, 0, 0))
+            kr_c = jax.lax.dynamic_update_slice(kr_c, kr, (0, 0, 0))
+            cache = {"ckv": ckv_c, "kr": kr_c}
+        return h, cache, aux_loss
 
     if kind == "griffin_attn":
         a, (k, v) = gqa_full(cfg, p["attn"], L.apply_norm(cfg, p["ln"], h), positions,
@@ -363,10 +376,11 @@ def apply_block_decode(cfg, kind, p, h, cache, aux):
         m, _ = L.moe_ffn(cfg, p["moe"], L.apply_norm(cfg, p["ln2"], h))
         return h + m, c
 
-    if kind == "mla_moe":
+    if kind in ("mla_moe", "mla_ffn"):
         a, c = mla_decode(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), pos, cache)
         h = h + a
-        m, _ = L.moe_ffn(cfg, p["moe"], L.apply_norm(cfg, p["ln2"], h))
+        y = L.apply_norm(cfg, p["ln2"], h)
+        m = L.ffn(cfg, p["mlp"], y) if kind == "mla_ffn" else L.moe_ffn(cfg, p["moe"], y)[0]
         return h + m, c
 
     if kind == "griffin_attn":
@@ -434,6 +448,7 @@ class Model:
     def __init__(self, cfg: ModelConfig, *, remat_policy: str = "none"):
         self.cfg = cfg
         self.remat_policy = remat_policy
+        self.lead = lead_blocks(cfg)
         self.cycle, self.n_cycles, self.tail = block_cycle(cfg)
 
     # ---- params ----
@@ -491,8 +506,23 @@ class Model:
 
     # ---- full-sequence stack ----
     def _run_stack(self, params, h, aux, collect_cache):
+        """The lead blocks, the scanned cycle, then the tail.  Returns
+        (h, aux_loss, caches by group as ``params['blocks']`` has them)."""
         cfg = self.cfg
         cycle = self.cycle
+        aux_loss = jnp.zeros((), jnp.float32)
+
+        def unrolled(group, kinds):
+            nonlocal h, aux_loss
+            caches = []
+            for j, kind in enumerate(kinds):
+                h, c_out, al = apply_block_full(cfg, kind, params["blocks"][group][j], h, aux,
+                                                collect_cache)
+                caches.append(c_out)
+                aux_loss = aux_loss + al
+            return caches
+
+        caches = {"lead": unrolled("lead", self.lead)} if self.lead else {}
 
         def body(carry, xs):
             hh, aux_acc = carry
@@ -504,15 +534,10 @@ class Model:
             return (hh, aux_acc), (cache_outs if collect_cache else None)
 
         body_fn = self._maybe_remat(body)
-        (h, aux_loss), cycle_caches = jax.lax.scan(
-            body_fn, (h, jnp.zeros((), jnp.float32)), params["blocks"]["cycle"])
-        tail_caches = []
-        for j, kind in enumerate(self.tail):
-            h, c_out, al = apply_block_full(cfg, kind, params["blocks"]["tail"][j], h, aux,
-                                            collect_cache)
-            tail_caches.append(c_out)
-            aux_loss = aux_loss + al
-        return h, aux_loss, cycle_caches, tail_caches
+        (h, aux_loss), caches["cycle"] = jax.lax.scan(
+            body_fn, (h, aux_loss), params["blocks"]["cycle"])
+        caches["tail"] = unrolled("tail", self.tail)
+        return h, aux_loss, caches
 
     # ---- public entry points ----
     def forward(self, params, batch):
@@ -528,7 +553,7 @@ class Model:
         if cfg.encoder_layers > 0:
             aux["enc_out"] = self.encode(params, batch["frame_embeds"])
         h = self._embed(params, tokens, positions, batch)
-        h, aux_loss, _, _ = self._run_stack(params, h, aux, collect_cache=False)
+        h, aux_loss, _ = self._run_stack(params, h, aux, collect_cache=False)
         h = L.apply_norm(cfg, params["final_norm"], h)
         return self._logits(params, h), aux_loss
 
@@ -544,12 +569,10 @@ class Model:
         if cfg.encoder_layers > 0:
             aux["enc_out"] = self.encode(params, batch["frame_embeds"])
         h = self._embed(params, tokens, positions, batch)
-        h, aux_loss, cycle_caches, tail_caches = self._run_stack(params, h, aux,
-                                                                 collect_cache=True)
+        h, aux_loss, caches = self._run_stack(params, h, aux, collect_cache=True)
         h = L.apply_norm(cfg, params["final_norm"], h)
         logits = self._logits(params, h[:, -1:])
-        cache = {"blocks": {"cycle": cycle_caches, "tail": tail_caches},
-                 "pos": jnp.full((B,), S, jnp.int32)}
+        cache = {"blocks": caches, "pos": jnp.full((B,), S, jnp.int32)}
         return logits, cache
 
     def decode_step(self, params, cache, batch):
@@ -566,6 +589,17 @@ class Model:
         h = self._embed(params, tokens, positions, batch)
         cycle = self.cycle
 
+        def unrolled(group, kinds):
+            nonlocal h
+            caches = []
+            for j, kind in enumerate(kinds):
+                h, cj = apply_block_decode(cfg, kind, params["blocks"][group][j], h,
+                                           cache["blocks"][group][j], aux)
+                caches.append(cj)
+            return caches
+
+        caches = {"lead": unrolled("lead", self.lead)} if self.lead else {}
+
         def body(hh, xs):
             p_slice, c_slice = xs
             new_c = []
@@ -574,15 +608,10 @@ class Model:
                 new_c.append(cj)
             return hh, new_c
 
-        h, cycle_caches = jax.lax.scan(
+        h, caches["cycle"] = jax.lax.scan(
             body, h, (params["blocks"]["cycle"], cache["blocks"]["cycle"]))
-        tail_caches = []
-        for j, kind in enumerate(self.tail):
-            h, cj = apply_block_decode(cfg, kind, params["blocks"]["tail"][j], h,
-                                       cache["blocks"]["tail"][j], aux)
-            tail_caches.append(cj)
+        caches["tail"] = unrolled("tail", self.tail)
         h = L.apply_norm(cfg, params["final_norm"], h)
         logits = self._logits(params, h)
-        new_cache = {"blocks": {"cycle": cycle_caches, "tail": tail_caches},
-                     "pos": pos + 1}
+        new_cache = {"blocks": caches, "pos": pos + 1}
         return logits, new_cache

@@ -8,7 +8,9 @@ One builder (`build_params`) drives four consumers via a creator callback:
 
 Block parameters are *stacked* over cycle repetitions (leading 'layer' dim)
 so the model can `lax.scan` over depth; a non-divisible remainder lives under
-``blocks['tail']`` unstacked.
+``blocks['tail']`` unstacked, and leading dense layers (DeepSeek-V3's
+``first_k_dense_replace``) under ``blocks['lead']``, unstacked, ahead of the
+scan; a model without them has no ``lead`` entry.
 """
 from __future__ import annotations
 
@@ -29,8 +31,14 @@ Creator = Callable[..., object]  # creator(path, shape, logical, fan_in) -> leaf
 # Block cycle resolution
 # --------------------------------------------------------------------------
 
+def lead_blocks(cfg: ModelConfig) -> tuple[str, ...]:
+    """Kinds of the leading dense layers, run before the scanned cycle."""
+    return ("mla_ffn" if cfg.attention == "mla" else "attn_ffn",) * cfg.first_k_dense
+
+
 def block_cycle(cfg: ModelConfig) -> tuple[tuple[str, ...], int, tuple[str, ...]]:
-    """Return (cycle_kinds, n_cycles, tail_kinds) for the decoder stack."""
+    """Return (cycle_kinds, n_cycles, tail_kinds) for the decoder stack after
+    the lead (:func:`lead_blocks`)."""
     if cfg.family in ("dense", "vlm"):
         cycle = ("attn_ffn",)
     elif cfg.family == "moe":
@@ -43,8 +51,9 @@ def block_cycle(cfg: ModelConfig) -> tuple[tuple[str, ...], int, tuple[str, ...]
         cycle = ("xattn",)
     else:
         raise ValueError(cfg.family)
-    n = cfg.num_layers // len(cycle)
-    tail_len = cfg.num_layers - n * len(cycle)
+    layers = cfg.num_layers - cfg.first_k_dense
+    n = layers // len(cycle)
+    tail_len = layers - n * len(cycle)
     return cycle, n, cycle[:tail_len]
 
 
@@ -115,9 +124,14 @@ def _mlp(cfg, c: Creator, path, d_ff=None, *, bias=False):
 
 
 def _moe(cfg, c: Creator, path):
-    D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    """The router scores all ``num_experts``; expert weights exist only for
+    the ``held_experts`` this device computes."""
+    D, E, F = cfg.d_model, cfg.held_experts, cfg.moe_d_ff
+    router = {"w": c(path + ("router", "w"), (D, cfg.num_experts), ("embed", None), D)}
+    if cfg.router == "noaux_tc":
+        router["bias"] = c(path + ("router", "bias"), (cfg.num_experts,), (None,), 0)
     p = {
-        "router": {"w": c(path + ("router", "w"), (D, E), ("embed", None), D)},
+        "router": router,
         "experts": {
             "gate": c(path + ("experts", "gate"), (E, D, F), ("expert", "embed", "expert_ffn"), D),
             "up": c(path + ("experts", "up"), (E, D, F), ("expert", "embed", "expert_ffn"), D),
@@ -245,6 +259,15 @@ def _moe_attn_ffn(cfg, c: Creator, path):
     }
 
 
+def _mla_ffn(cfg, c: Creator, path):
+    return {
+        "ln1": _norm(cfg, c, path + ("ln1",)),
+        "attn": _mla_attn(cfg, c, path + ("attn",)),
+        "ln2": _norm(cfg, c, path + ("ln2",)),
+        "mlp": _mlp(cfg, c, path + ("mlp",)),
+    }
+
+
 def _mla_moe(cfg, c: Creator, path):
     return {
         "ln1": _norm(cfg, c, path + ("ln1",)),
@@ -257,6 +280,7 @@ def _mla_moe(cfg, c: Creator, path):
 BLOCK_BUILDERS = {
     "attn_ffn": _attn_ffn,
     "moe_attn_ffn": _moe_attn_ffn,
+    "mla_ffn": _mla_ffn,
     "mla_moe": _mla_moe,
     "griffin_rec": _griffin_rec,
     "griffin_attn": _griffin_attn,
@@ -285,7 +309,10 @@ def build_params(cfg: ModelConfig, creator: Creator) -> dict:
         "final_norm": _norm(cfg, creator, ("final_norm",)),
     }
     sc = _stacked_creator(creator, n)
+    lead = lead_blocks(cfg)
     tree["blocks"] = {
+        **({"lead": [BLOCK_BUILDERS[kind](cfg, creator, ("blocks", "lead", str(j), kind))
+                     for j, kind in enumerate(lead)]} if lead else {}),
         "cycle": [BLOCK_BUILDERS[kind](cfg, sc, ("blocks", "cycle", str(j), kind))
                   for j, kind in enumerate(cycle)],
         "tail": [BLOCK_BUILDERS[kind](cfg, creator, ("blocks", "tail", str(j), kind))
@@ -355,8 +382,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=None):
 
 @functools.lru_cache(maxsize=512)
 def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
-    """Total parameter count; ``active_only`` counts top-k routed experts only
-    (MoE active params for MODEL_FLOPS = 6 * N_active * D).  Pure in the
+    """Total parameter count of what this device holds (its share of the
+    experts); ``active_only`` counts each held expert by the share of tokens
+    routed to it, top_k / num_experts (MoE active params for MODEL_FLOPS =
+    6 * N_active * D).  Pure in the
     frozen config, so memoized — the simulator calls it on every report and
     sweeps call it per candidate."""
     total = [0]

@@ -115,14 +115,12 @@ class ServingEngine:
 
     def _scatter(self, slot: int, pc: dict, pos: int, tok: int):
         """The single-request (batch=1) cache into ``slot``, its position and
-        its last token (cycle leaves are layer-stacked: batch is dim 1;
-        tail: dim 0)."""
-        self.cache["blocks"]["cycle"] = jax.tree.map(
-            lambda c, o: c.at[:, slot].set(o[:, 0]) if c.ndim >= 2 else c,
-            self.cache["blocks"]["cycle"], pc["blocks"]["cycle"])
-        self.cache["blocks"]["tail"] = jax.tree.map(
-            lambda c, o: c.at[slot].set(o[0]) if c.ndim >= 1 else c,
-            self.cache["blocks"]["tail"], pc["blocks"]["tail"])
+        its last token, for every cache group (``cycle`` leaves are
+        layer-stacked: batch is dim 1; ``lead`` and ``tail``: dim 0)."""
+        for group, own in pc["blocks"].items():
+            at = (slice(None),) if group == "cycle" else ()
+            self.cache["blocks"][group] = jax.tree.map(
+                lambda c, o: c.at[(*at, slot)].set(o[(*at, 0)]), self.cache["blocks"][group], own)
         self.cache["pos"] = self.cache["pos"].at[slot].set(pos)
         self._last_tok = self._last_tok.at[slot, 0].set(tok)
 

@@ -40,8 +40,6 @@ def test_forward_shapes_finite(arch):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_train_step_decreases_nothing_nan(arch):
     cfg = get_tiny_config(arch)
-    if cfg.num_experts:
-        cfg = cfg.replace(capacity_factor=4.0)
     run = RunConfig(model=cfg, shape=ShapeConfig("t", 16, 2, "train"))
     opt = make_optimizer("adamw", peak_lr=1e-3)
     step = jax.jit(make_train_step(cfg, run, opt))
@@ -58,8 +56,6 @@ def test_train_step_decreases_nothing_nan(arch):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_prefill_decode_match_forward(arch):
     cfg = get_tiny_config(arch)
-    if cfg.num_experts:
-        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
     m = Model(cfg)
     params = m.init(jax.random.PRNGKey(0))
     B, S = 2, 12

@@ -16,7 +16,7 @@ import pytest
 from repro.configs import ARCH_IDS, get_tiny_config
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.models import Model, zero_cache
-from repro.models.params import block_cycle
+from repro.models.params import block_cycle, lead_blocks
 from repro.serving import Request, ServingEngine
 from repro.training.optimizer import adamw, cosine_schedule
 from repro.training.train_step import make_train_step
@@ -156,8 +156,10 @@ def op_names(fn, *args) -> set:
 
 
 # The scopes each block kind opens inside its own.
-KIND_SCOPES = {"attn_ffn": ("attn", "ffn"), "moe_attn_ffn": ("attn", "moe"),
-               "mla_moe": ("attn", "moe"), "griffin_attn": ("attn", "ffn"),
+MOE_SCOPES = ("moe", "moe/moe_route", "moe/moe_experts")
+KIND_SCOPES = {"attn_ffn": ("attn", "ffn"), "moe_attn_ffn": ("attn", *MOE_SCOPES),
+               "mla_moe": ("attn", *MOE_SCOPES), "mla_ffn": ("attn", "ffn"),
+               "griffin_attn": ("attn", "ffn"),
                "griffin_rec": ("ffn",), "xattn": ("attn", "ffn"), "mlstm": (),
                "slstm": ()}
 
@@ -174,7 +176,7 @@ def test_decode_step_scopes(arch):
     joined = "\n".join(names)
     assert "jit(decode_step)/embed/" in joined and "jit(decode_step)/head/" in joined
     cycle, _, tail = block_cycle(cfg)
-    for kind in {*cycle, *tail}:
+    for kind in {*lead_blocks(cfg), *cycle, *tail}:
         assert f"/{kind}/" in joined
         for scope in KIND_SCOPES[kind]:
             assert f"/{kind}/{scope}/" in joined, (kind, scope)

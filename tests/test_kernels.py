@@ -39,6 +39,19 @@ def test_flash_attention_sweep(case):
                                atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 2e-2)])
+def test_flash_attention_value_width(dtype, tol):
+    """MLA's shapes: v narrower than q/k, a scale of its own, and causal kv
+    blocks skipped (q blocks of 32 rows against kv blocks of 64)."""
+    q, k, _ = _qkv(1, 4, 4, 256, 256, 48, dtype)
+    v = jax.random.normal(jax.random.PRNGKey(3), (1, 4, 256, 32), jnp.float32).astype(dtype)
+    out = fa_raw(q, k, v, causal=True, scale=0.3, interpret=True, block_q=32, block_k=64)
+    want = ref.flash_attention_ref(q, k, v, causal=True, scale=0.3)
+    assert out.shape == (1, 4, 256, 32)
+    np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
+                               atol=tol, rtol=tol)
+
+
 DEC_CASES = [
     (2, 4, 2, 256, 64, jnp.float32, 2e-6),
     (1, 8, 1, 300, 64, jnp.float32, 2e-6),   # MQA, ragged splits
@@ -93,4 +106,21 @@ def test_flash_matches_model_layout():
     out = ops.flash_attention_bshd(q, k, v, causal=True, interpret=True)
     want = L.attend_blockwise(q, k, v, q_offset=0, causal=True,
                               q_block=64, kv_block=64)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+def test_flash_matches_model_layout_for_mla():
+    """The bshd wrapper on MLA's layout (every head its own K and V, v
+    narrower than q/k, a scale of its own) agrees with the blockwise path."""
+    from repro.models import layers as L
+    B, S, H, Dq, Dv = 1, 256, 4, 48, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, S, H, 1, Dq))
+    k = jax.random.normal(ks[1], (B, S, H, Dq))
+    v = jax.random.normal(ks[2], (B, S, H, Dv))
+    out = ops.flash_attention_bshd(q, k, v, causal=True, scale=0.2, block_q=64,
+                                   block_k=64, interpret=True)
+    want = L.attend_blockwise(q, k, v, q_offset=0, causal=True, scale=0.2,
+                              q_block=64, kv_block=64)
+    assert out.shape == (B, S, H, 1, Dv)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)

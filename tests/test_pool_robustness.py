@@ -130,8 +130,16 @@ def test_candidate_error_recovery_bit_identical_serial_and_pool():
 # quarantine: retries exhausted -> FailedCandidate, never an abort
 # ======================================================================
 
-# fires on every attempt for 4 of the 18 candidates (verified schedule)
+# fires on every attempt for some of the 18 candidates (`_poisoned` names them)
 POISON = FaultPlan(seed=1, candidate_error=0.2, repeat=True)
+
+
+def _poisoned(space) -> list[str]:
+    """The hashes of the candidates POISON fails on every attempt: its
+    schedule rolls on each spec's JSON hash, so which of the 18 it hits
+    follows every field of the spec, the model config's included."""
+    return sorted(h for h in (s.json_hash() for s in space.points())
+                  if POISON.roll("candidate_error", h))
 ONE_RETRY = RetryPolicy(max_retries=1, timeout_s=5.0, backoff_s=0.01,
                         backoff_max_s=0.1)
 
@@ -139,7 +147,11 @@ ONE_RETRY = RetryPolicy(max_retries=1, timeout_s=5.0, backoff_s=0.01,
 def test_quarantine_is_symmetric_between_serial_and_pool():
     ser = sweep(_space(), faults=POISON, retry=ONE_RETRY)
     par = sweep(_space(), workers=2, faults=POISON, retry=ONE_RETRY)
-    assert len(ser.failed) == len(par.failed) == 4
+    poisoned = _poisoned(_space())
+    n = len(poisoned)
+    assert n >= 2
+    assert len(ser.failed) == len(par.failed) == n
+    assert sorted(f.spec.json_hash() for f in ser.failed) == poisoned
     assert [f.spec.json_hash() for f in ser.failed] \
         == [f.spec.json_hash() for f in par.failed]
     for f in ser.failed + par.failed:
@@ -147,10 +159,10 @@ def test_quarantine_is_symmetric_between_serial_and_pool():
         assert "ChaosError" in f.reason
     # the poisoned candidates are *missing* from evaluated, not silently
     # re-classified as pruned
-    assert len(ser.evaluated) + len(ser.pruned) == 18 - 4
+    assert len(ser.evaluated) + len(ser.pruned) == 18 - n
     assert _result_key(ser) == _result_key(par)
-    assert _counters(par).get("pool.quarantined", 0) == 4
-    assert _counters(par).get("sweep.failed", 0) == 4
+    assert _counters(par).get("pool.quarantined", 0) == n
+    assert _counters(par).get("sweep.failed", 0) == n
 
 
 def test_strict_mode_fails_fast():
@@ -172,7 +184,7 @@ def test_manifest_records_failed_rows(tmp_path):
     statuses = {}
     for row in doc["candidates"]:
         statuses[row["status"]] = statuses.get(row["status"], 0) + 1
-    assert statuses["failed"] == doc["n_failed"] == len(res.failed) == 4
+    assert statuses["failed"] == doc["n_failed"] == len(res.failed) == len(_poisoned(_space()))
     assert statuses["completed"] == len(res.evaluated)
     frow = next(r for r in doc["candidates"] if r["status"] == "failed")
     assert frow["attempts"] == 2 and "ChaosError" in frow["reason"]
@@ -218,13 +230,14 @@ def test_journal_failed_rows_are_reattempted_on_resume(tmp_path):
     jr = tmp_path / "sweep.jsonl"
     broken = sweep(_space(), faults=POISON, retry=ONE_RETRY,
                    journal=str(jr))
-    assert len(broken.failed) == 4
+    n = len(_poisoned(_space()))
+    assert n >= 2 and len(broken.failed) == n
     # resume without faults: the quarantined candidates get their second
     # chance and the merged result matches a clean run exactly
     healed = sweep(_space(), journal=str(jr))
     assert healed.failed == ()
     assert _result_key(healed) == _result_key(sweep(_space()))
-    assert _counters(healed).get("sweep.resumed", 0) == 14
+    assert _counters(healed).get("sweep.resumed", 0) == 18 - n
 
 
 _KILL_HARNESS = """

@@ -150,8 +150,7 @@ from repro.distributed.sharding import ShardingEnv, activate
 from repro.models import Model, init_params
 from repro.training.train_step import param_pspecs, to_named
 
-cfg = get_tiny_config("olmoe-1b-7b").replace(capacity_factor=8.0,
-                                             dtype="float32", param_dtype="float32")
+cfg = get_tiny_config("olmoe-1b-7b").replace(dtype="float32", param_dtype="float32")
 m = Model(cfg)
 params = init_params(cfg, jax.random.PRNGKey(0))
 B, S = 4, 16

@@ -64,6 +64,18 @@ def test_flash_attention_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_mla_prefill_flash_attention_compiles(one_chip):
+    """DeepSeek-V3's prefill attention at its published widths: 128 heads,
+    q/k 192 wide, v 128, a 4096-token prompt, the tiles ``mla_full`` uses."""
+    from repro.models.model import MLA_KV_BLOCK, MLA_Q_BLOCK
+    S, H = 4096, 128
+    compiled = ops.flash_attention_bshd.lower(
+        _sds((1, S, H, 1, 192), one_chip), _sds((1, S, H, 192), one_chip),
+        _sds((1, S, H, 128), one_chip), causal=True, scale=0.1,
+        block_q=MLA_Q_BLOCK, block_k=MLA_KV_BLOCK).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_decode_attention_compiles(one_chip):
     B, H, Hkv, T, D = 4, CFG.num_heads, CFG.num_kv_heads, 2048, CFG.head_dim
     compiled = ops.decode_attention.lower(
