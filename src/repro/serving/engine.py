@@ -22,9 +22,13 @@ and ``compiled``: the dispatch of the jitted batch-1 prefill, and, where
 not prefilled before), ``engine.first_token`` (the wait for the prefill's
 device work and the host read of its first token, the TTFT stamp) and
 ``engine.scatter`` (its cache rows into the slot).  Per decode step:
-``engine.decode`` (arg ``active``) holding ``engine.sample`` (the argmax and
-the host read of each slot's token).  ``docs/observability.md`` says how to
-capture them.
+``engine.decode`` (arg ``active``) holding ``engine.sample`` (arg
+``tokens``: the argmax and one host read of every live slot's token).
+``docs/observability.md`` says how to capture them.
+
+``ServingEngine.host_reads`` counts the engine's device-to-host reads: one
+per admission (its first token) and one per decode step, whatever the
+number of live slots.
 """
 from __future__ import annotations
 
@@ -78,6 +82,7 @@ class ServingEngine:
         self._prefilled: set[int] = set()        # prompt lengths seen
         self._last_tok = jnp.zeros((slots, 1), jnp.int32)
         self.finished: list[Request] = []
+        self.host_reads = 0                      # device-to-host reads made
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
@@ -105,6 +110,7 @@ class ServingEngine:
                     logits, pc = self._prefill(self.params, {"tokens": prompt})
                 with TraceAnnotation("engine.first_token", rid=req.rid):
                     tok = int(jnp.argmax(logits[0, -1]))
+                    self.host_reads += 1
                     now = self.clock()
                     req.tokens.append(tok)
                     req.token_s.append(now)
@@ -133,10 +139,11 @@ class ServingEngine:
         with TraceAnnotation("engine.decode", active=len(self.active)):
             logits, self.cache = self._decode(self.params, self.cache,
                                               {"tokens": self._last_tok})
-            with TraceAnnotation("engine.sample"):
+            with TraceAnnotation("engine.sample", tokens=len(self.active)):
                 next_tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                 self._last_tok = next_tok[:, None]
-                toks = {slot: int(next_tok[slot]) for slot in self.active}
+                toks = jax.device_get(next_tok).tolist()   # one read, every slot
+                self.host_reads += 1
             now = self.clock()
             done = []
             for slot, req in self.active.items():

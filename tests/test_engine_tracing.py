@@ -94,6 +94,42 @@ def test_the_six_spans_are_on_the_timeline(traced):
     assert len(decodes) >= max(MAX_NEW) - 1
 
 
+def test_sample_reads_every_live_slot_at_once(traced):
+    """Each ``engine.sample`` sits in its ``engine.decode`` and carries the
+    number of live slots it read: their sum is every token served after the
+    first ones, which the admissions read."""
+    done, events = traced
+    samples = []
+    for d in (ev for ev in events if ev[0] == "engine.decode"):
+        (s,) = inside(d, events)
+        assert s[0] == "engine.sample" and s[3]["tokens"] == d[3]["active"]
+        samples.append(s[3]["tokens"])
+    assert 2 in samples
+    assert sum(samples) == sum(len(r.tokens) - 1 for r in done.values())
+
+
+def test_one_host_read_per_decode_step(monkeypatch):
+    """A decode step reads its tokens in one ``jax.device_get``, with 1, 2
+    or 3 slots live; ``host_reads`` grows by one per step and one per
+    admission (its first token)."""
+    gets = []
+    device_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda x: gets.append(x) or device_get(x))
+    cfg = get_tiny_config("phi4-mini-3.8b")
+    params = Model(cfg).init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, slots=3, cache_len=32)
+    # one admission in each of the first three steps, then two plain steps
+    for step, live in enumerate([1, 2, 3, 3, 3]):
+        if step < len(PROMPTS):
+            eng.submit(Request(rid=step, prompt=PROMPTS[step], max_new_tokens=8))
+        reads, before = eng.host_reads, len(gets)
+        assert eng.step() == live
+        assert eng.host_reads - reads == (step < len(PROMPTS)) + 1
+        assert len(gets) - before == 1 and gets[-1].shape == (3,)
+    assert eng.host_reads == len(PROMPTS) + 5
+    assert [len(r.tokens) for r in eng.active.values()] == [6, 5, 4]
+
+
 def test_request_stamps(traced):
     done, _ = traced
     assert sorted(done) == [0, 1, 2]

@@ -45,6 +45,37 @@ def test_serving_engine_continuous_batching_matches_sequential():
     assert req.tokens == out
 
 
+def test_serving_engine_three_slots_at_mixed_depths_match_sequential():
+    """Three requests admitted two steps apart decode together at three
+    depths; each one's tokens are a sequential prefill/decode_step's, and
+    each is a Python int."""
+    cfg = get_tiny_config("gemma-7b")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, slots=3, cache_len=64)
+    prompts = [[1, 2, 3, 4], [9, 8, 7], [5, 5, 5, 5, 5, 5]]
+    max_new = [10, 8, 6]
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=max_new[i]))
+        eng.step()
+        eng.step()
+    assert len(eng.active) == 3
+    assert len({int(p) for p in eng.cache["pos"]}) == 3
+    finished = {r.rid: r for r in eng.run_until_drained(max_steps=200)}
+    assert sorted(finished) == [0, 1, 2]
+    for i, p in enumerate(prompts):
+        toks = finished[i].tokens
+        assert all(type(t) is int for t in toks)
+        logits, cache = model.prefill(params, {"tokens": jnp.asarray([p], jnp.int32)},
+                                      cache_len=64)
+        out = [int(jnp.argmax(logits[0, -1]))]
+        while len(out) < max_new[i]:
+            logits, cache = model.decode_step(params, cache,
+                                              {"tokens": jnp.asarray([[out[-1]]], jnp.int32)})
+            out.append(int(jnp.argmax(logits[0, 0])))
+        assert toks == out, i
+
+
 def test_serving_engine_virtual_clock_trace_replay():
     """Caller-supplied arrival_s (including 0.0) must be honored and TTFT
     computed on the injected clock's timebase, not wall-clock."""
