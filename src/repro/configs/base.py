@@ -44,8 +44,6 @@ class ModelConfig:
     yarn_original_max_pos: int = 4096
     window: int = 0                  # sliding-window size (0 = full attention)
     logit_soft_cap: float = 0.0
-    attn_score_dtype: str = "float32"   # bfloat16: flash-style low-prec P*V path
-    attn_kv_block: int = 512             # blockwise-attention KV tile
 
     # --- ffn ---
     act: str = "swiglu"              # swiglu | geglu | gelu

@@ -2,8 +2,9 @@
 
 Charon extracts and simulates a single transformer block per distinct block
 kind and extrapolates over depth; asymmetric stacks (whisper enc/dec,
-recurrentgemma hybrid cycle) trace each kind separately.  Attention is traced
-as a single abstract operator via core/stubs.py.
+recurrentgemma hybrid cycle) trace each kind separately.  The block
+functions are handed core/stubs.py's ``attention_stub`` as their attention,
+so each attention is traced as a single abstract operator.
 
 All graphs are traced at the *per-data-shard* batch (B_local); the
 parallelism passes then rewrite for TP/SP/EP/CP.
@@ -18,10 +19,10 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import tracer
 from repro.core.ir import Graph
-from repro.core.stubs import ingest_attention
+from repro.core.stubs import attention_stub
 from repro.models import abstract_params, block_cycle, lead_blocks
 from repro.models.kvcache import build_cache
-from repro.models.model import Model, apply_block_decode, apply_block_full
+from repro.models.model import apply_block_decode, apply_block_full
 
 
 @dataclass
@@ -315,94 +316,93 @@ def block_graphs(cfg: ModelConfig, B_local: int, S: int, mode: str,
         if cfg.cross_attention else None
 
     blocks: list[BlockGraphs] = []
-    with ingest_attention():
-        seen_kinds: dict[str, BlockGraphs] = {}
-        for kind, group, j, repeat in layers:
-            if kind in seen_kinds:
-                seen_kinds[kind].repeat += repeat
-                continue
-            p_abs = _layer_slice(abstract_params(cfg), group, j)
-            if mode == "decode":
-                cache_abs = _layer_slice(build_cache(
-                    cfg, lambda s, l, d: jax.ShapeDtypeStruct(s, d), B_local,
-                    cache_len or S), group, j)
-                x1 = jax.ShapeDtypeStruct((B_local, 1, D), dt)
-                posv = jax.ShapeDtypeStruct((B_local,), jnp.int32)
+    seen_kinds: dict[str, BlockGraphs] = {}
+    for kind, group, j, repeat in layers:
+        if kind in seen_kinds:
+            seen_kinds[kind].repeat += repeat
+            continue
+        p_abs = _layer_slice(abstract_params(cfg), group, j)
+        if mode == "decode":
+            cache_abs = _layer_slice(build_cache(
+                cfg, lambda s, l, d: jax.ShapeDtypeStruct(s, d), B_local,
+                cache_len or S), group, j)
+            x1 = jax.ShapeDtypeStruct((B_local, 1, D), dt)
+            posv = jax.ShapeDtypeStruct((B_local,), jnp.int32)
 
-                def dec_fn(p, x, cache, pos, _kind=kind):
-                    aux = {"pos": pos, "decode_positions": pos[:, None]}
-                    h, c = apply_block_decode(cfg, _kind, p, x, cache, aux)
-                    return h
-
-                fwd = _tag_moe(tracer.trace(dec_fn, p_abs, x1, cache_abs, posv,
-                                            name=f"{kind}.decode"), cfg)
-                bg = BlockGraphs(kind, repeat, fwd)
-            else:
-                def fwd_fn(p, x, positions, enc=None, _kind=kind):
-                    aux = {"positions": positions, "cache_len": 0}
-                    if enc is not None:
-                        aux["enc_out"] = enc
-                    h, _, aux_l = apply_block_full(cfg, _kind, p, x, aux, False)
-                    return h if mode != "train" else (h, aux_l)
-
-                args = (p_abs, x_abs, pos_abs) + ((enc_abs,) if enc_abs is not None else ())
-                if mode == "train":
-                    fwd = _tag_moe(tracer.trace(
-                        lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.fwd"), cfg)
-                    joint = _tag_moe(tracer.trace_grad(
-                        lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.joint"), cfg)
-                    bg = BlockGraphs(kind, repeat, fwd, joint)
-                else:
-                    fwd = _tag_moe(tracer.trace(fwd_fn, *args, name=f"{kind}.fwd"), cfg)
-                    bg = BlockGraphs(kind, repeat, fwd)
-            seen_kinds[kind] = bg
-            blocks.append(bg)
-
-        # encoder (whisper)
-        encoder = None
-        if cfg.encoder_layers > 0 and mode != "decode":
-            p_enc = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
-                abstract_params(cfg)["encoder"]["blocks"]["cycle"][0])
-            xe = jax.ShapeDtypeStruct((B_local, cfg.encoder_seq, D), dt)
-            pe = jax.ShapeDtypeStruct((B_local, cfg.encoder_seq), jnp.int32)
-
-            def enc_fn(p, x, positions):
-                h, _, _ = apply_block_full(cfg, "enc", p, x,
-                                           {"positions": positions}, False)
+            def dec_fn(p, x, cache, pos, _kind=kind):
+                aux = {"pos": pos, "decode_positions": pos[:, None]}
+                h, c = apply_block_decode(cfg, _kind, p, x, cache, aux,
+                                          attention=attention_stub)
                 return h
 
-            efwd = tracer.trace(enc_fn, p_enc, xe, pe, name="enc.fwd")
-            ejoint = tracer.trace_grad(enc_fn, p_enc, xe, pe, name="enc.joint") \
-                if mode == "train" else None
-            encoder = BlockGraphs("enc", cfg.encoder_layers, efwd, ejoint)
+            fwd = _tag_moe(tracer.trace(dec_fn, p_abs, x1, cache_abs, posv,
+                                        name=f"{kind}.decode"), cfg)
+            bg = BlockGraphs(kind, repeat, fwd)
+        else:
+            def fwd_fn(p, x, positions, enc=None, _kind=kind):
+                aux = {"positions": positions, "cache_len": 0}
+                if enc is not None:
+                    aux["enc_out"] = enc
+                h, _, aux_l = apply_block_full(cfg, _kind, p, x, aux, False,
+                                               attention=attention_stub)
+                return h if mode != "train" else (h, aux_l)
 
-        # embed + head (+ CE loss for train)
-        model = Model(cfg)
-        S_head = 1 if mode == "decode" else S
-        tok_abs = jax.ShapeDtypeStruct((B_local, S_head), jnp.int32)
-        emb_abs = jax.ShapeDtypeStruct((cfg.vocab_size, D), jnp.dtype(cfg.param_dtype))
-        nrm_abs = {"w": jax.ShapeDtypeStruct((D,), jnp.dtype(cfg.param_dtype))}
-        if cfg.norm == "layernorm":
-            nrm_abs["b"] = jax.ShapeDtypeStruct((D,), jnp.dtype(cfg.param_dtype))
-        h_abs = jax.ShapeDtypeStruct((B_local, S_head, D), dt)
-
-        def head_fn(emb_w, nrm, h, tokens):
-            from repro.models import layers as L
-            params = {"embed": {"w": emb_w}, "final_norm": nrm}
-            x = jnp.take(emb_w, tokens, axis=0).astype(dt)
-            hh = h + x * 0  # keep both paths alive
-            hh = L.apply_norm(cfg, nrm, hh)
-            logits = jnp.einsum("bsd,dv->bsv", hh, emb_w.T.astype(dt)).astype(jnp.float32)
+            args = (p_abs, x_abs, pos_abs) + ((enc_abs,) if enc_abs is not None else ())
             if mode == "train":
-                logp = jax.nn.log_softmax(logits, axis=-1)
-                return -jnp.mean(jnp.take_along_axis(
-                    logp, jnp.maximum(tokens, 0)[..., None], axis=-1))
-            return logits
+                fwd = _tag_moe(tracer.trace(
+                    lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.fwd"), cfg)
+                joint = _tag_moe(tracer.trace_grad(
+                    lambda *a: fwd_fn(*a)[0], *args, name=f"{kind}.joint"), cfg)
+                bg = BlockGraphs(kind, repeat, fwd, joint)
+            else:
+                fwd = _tag_moe(tracer.trace(fwd_fn, *args, name=f"{kind}.fwd"), cfg)
+                bg = BlockGraphs(kind, repeat, fwd)
+        seen_kinds[kind] = bg
+        blocks.append(bg)
 
-        hf = tracer.trace(head_fn, emb_abs, nrm_abs, h_abs, tok_abs, name="head.fwd")
-        hj = tracer.trace_grad(head_fn, emb_abs, nrm_abs, h_abs, tok_abs,
-                               name="head.joint") if mode == "train" else None
-        head = BlockGraphs("head", 1, hf, hj)
+    # encoder (whisper)
+    encoder = None
+    if cfg.encoder_layers > 0 and mode != "decode":
+        p_enc = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
+            abstract_params(cfg)["encoder"]["blocks"]["cycle"][0])
+        xe = jax.ShapeDtypeStruct((B_local, cfg.encoder_seq, D), dt)
+        pe = jax.ShapeDtypeStruct((B_local, cfg.encoder_seq), jnp.int32)
+
+        def enc_fn(p, x, positions):
+            h, _, _ = apply_block_full(cfg, "enc", p, x, {"positions": positions},
+                                       False, attention=attention_stub)
+            return h
+
+        efwd = tracer.trace(enc_fn, p_enc, xe, pe, name="enc.fwd")
+        ejoint = tracer.trace_grad(enc_fn, p_enc, xe, pe, name="enc.joint") \
+            if mode == "train" else None
+        encoder = BlockGraphs("enc", cfg.encoder_layers, efwd, ejoint)
+
+    # embed + head (+ CE loss for train)
+    S_head = 1 if mode == "decode" else S
+    tok_abs = jax.ShapeDtypeStruct((B_local, S_head), jnp.int32)
+    emb_abs = jax.ShapeDtypeStruct((cfg.vocab_size, D), jnp.dtype(cfg.param_dtype))
+    nrm_abs = {"w": jax.ShapeDtypeStruct((D,), jnp.dtype(cfg.param_dtype))}
+    if cfg.norm == "layernorm":
+        nrm_abs["b"] = jax.ShapeDtypeStruct((D,), jnp.dtype(cfg.param_dtype))
+    h_abs = jax.ShapeDtypeStruct((B_local, S_head, D), dt)
+
+    def head_fn(emb_w, nrm, h, tokens):
+        from repro.models import layers as L
+        x = jnp.take(emb_w, tokens, axis=0).astype(dt)
+        hh = h + x * 0  # keep both paths alive
+        hh = L.apply_norm(cfg, nrm, hh)
+        logits = jnp.einsum("bsd,dv->bsv", hh, emb_w.T.astype(dt)).astype(jnp.float32)
+        if mode == "train":
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.mean(jnp.take_along_axis(
+                logp, jnp.maximum(tokens, 0)[..., None], axis=-1))
+        return logits
+
+    hf = tracer.trace(head_fn, emb_abs, nrm_abs, h_abs, tok_abs, name="head.fwd")
+    hj = tracer.trace_grad(head_fn, emb_abs, nrm_abs, h_abs, tok_abs,
+                           name="head.joint") if mode == "train" else None
+    head = BlockGraphs("head", 1, hf, hj)
 
     return ModelGraphs(cfg, mode, blocks, head, encoder)

@@ -7,19 +7,15 @@ is a JAX primitive with abstract evaluation only — simulation never executes
 it; ``jax.make_jaxpr`` is enough.  A custom_vjp routes backward tracing to a
 ``charon_attention_bwd`` primitive.
 
-``attention_stub(...)`` is installed into ``repro.models.layers`` by the
-:func:`ingest_attention` context manager during tracing.
+The ingest passes :func:`attention_stub` to the model's block functions
+(their ``attention`` argument) in place of ``layers.attention``.
 """
 from __future__ import annotations
 
-import contextlib
-import math
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 from jax.extend import core as jex_core
-from jax.interpreters import ad
 
 attention_p = jex_core.Primitive("charon_attention")
 attention_bwd_p = jex_core.Primitive("charon_attention_bwd")
@@ -57,22 +53,9 @@ _attn.defvjp(_attn_fwd, _attn_bwd)
 
 
 def attention_stub(q, k, v, *, q_offset=0, causal=True, window=0,
-                   kv_valid_len=None, soft_cap=0.0, strategy="auto",
-                   scale=None, q_block=2048, kv_block=512, score_dtype=None):
-    """Signature-compatible replacement for layers.attention."""
+                   kv_valid_len=None, soft_cap=0.0, scale=None, flash=False):
+    """``layers.attention``'s signature, traced as one abstract operator."""
     return _attn(q, k, v, causal, int(window))
-
-
-@contextlib.contextmanager
-def ingest_attention():
-    """Swap layers.attention for the abstract stub while tracing."""
-    from repro.models import layers as L
-    orig = L.attention
-    L.attention = attention_stub
-    try:
-        yield
-    finally:
-        L.attention = orig
 
 
 def attention_flops(q_shape, v_shape, *, causal: bool, window: int) -> float:

@@ -1,6 +1,4 @@
 from repro.kernels import ops, ref
-from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.rmsnorm import rmsnorm
 
-__all__ = ["ops", "ref", "decode_attention", "flash_attention", "rmsnorm"]
+__all__ = ["ops", "ref", "flash_attention"]

@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the Pallas kernels (the allclose targets)."""
+"""Pure-jnp oracle for the Pallas flash-attention kernel (the allclose target)."""
 from __future__ import annotations
 
 import math
@@ -29,30 +29,3 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhst,bhtd->bhsd", p, vv.astype(jnp.float32)).astype(q.dtype)
-
-
-def decode_attention_ref(q, k, v, *, kv_valid_len=None, scale=None):
-    """q: (B,H,D); k/v: (B,Hkv,T,D) -> (B,H,D)."""
-    B, H, D = q.shape
-    Hkv, T = k.shape[1], k.shape[2]
-    g = H // Hkv
-    kk = jnp.repeat(k, g, axis=1)
-    vv = jnp.repeat(v, g, axis=1)
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    s = jnp.einsum("bhd,bhtd->bht", q.astype(jnp.float32),
-                   kk.astype(jnp.float32)) * scale
-    if kv_valid_len is not None:
-        s = jnp.where(jnp.arange(T)[None, None, :] < kv_valid_len[:, None, None],
-                      s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bht,bhtd->bhd", p, vv.astype(jnp.float32)).astype(q.dtype)
-
-
-def rmsnorm_ref(x, w, *, eps=1e-6, offset=False, residual=None):
-    if residual is not None:
-        x = x + residual
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(var + eps)
-    scale = (1.0 + w.astype(jnp.float32)) if offset else w.astype(jnp.float32)
-    return (y * scale).astype(x.dtype)

@@ -5,11 +5,10 @@ logical sharding constraints via :mod:`repro.distributed.sharding` so the same
 code lowers correctly on 1 CPU device, a 16x16 pod, or the 2x16x16 multi-pod
 mesh.
 
-Attention has three execution strategies:
-  * ``dense``     — plain einsum softmax attention (small sequences, tests)
-  * ``blockwise`` — lax.scan online-softmax attention (memory-safe at 32k+;
-                    the XLA analogue of the Pallas flash kernel)
-  * ``pallas``    — repro.kernels flash attention (TPU only; MLA prefill)
+Every attention goes through :func:`attention`, which alone chooses how it
+runs (:func:`attention_plan`): plain einsum softmax (``dense``), lax.scan
+online softmax (``blockwise``, memory-safe at 32k+), or the Pallas flash
+kernel (``flash``, one TPU, a forward that is not differentiated).
 """
 from __future__ import annotations
 
@@ -21,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.sharding import logical_constraint as shard
+from repro.distributed.sharding import active_env, axis_size, logical_constraint as shard
 
 
 # --------------------------------------------------------------------------
@@ -186,19 +185,12 @@ def attend_dense(q, k, v, *, q_offset, causal: bool, window: int = 0,
 
 def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
                      kv_valid_len=None, soft_cap: float = 0.0,
-                     q_block: int = 512, kv_block: int = 1024,
-                     scale: float | None = None, skip_masked_blocks: bool = True,
-                     score_dtype=jnp.float32):
-    """Online-softmax (flash-style) attention in pure JAX.
+                     q_block: int, kv_block: int, scale: float | None = None):
+    """Online-softmax (flash-style) attention in pure JAX, in float32.
 
-    Outer Python loop over q blocks (static trip count) so causal runs can
-    statically truncate the KV range per q block (``skip_masked_blocks``);
+    Outer Python loop over q blocks (static trip count) so causal and
+    windowed runs can statically skip the kv blocks a q block never sees;
     inner ``lax.scan`` over kv blocks carries the running (m, l, acc).
-
-    ``score_dtype=bfloat16`` keeps the probability tensor (the dominant HBM
-    intermediate at 32k sequence) in bf16 for the PV matmul while the running
-    max/sum statistics stay fp32 — the XLA analogue of the Pallas kernel's
-    VMEM-resident scores (see EXPERIMENTS.md §Perf).
     """
     B, Sq, Hkv, G, Dq = q.shape
     T, Dv = k.shape[1], v.shape[-1]
@@ -212,7 +204,6 @@ def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
     kp = jnp.pad(k, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
     n_kv = T_p // kv_block
-    t_pos_full = jnp.arange(T_p)
 
     if kv_valid_len is not None:
         vl = jnp.asarray(kv_valid_len)
@@ -224,11 +215,11 @@ def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
         # static causal truncation: kv blocks strictly after this q block's
         # last row are fully masked -> skip (saves ~2x flops at scale)
         hi = n_kv
-        if causal and skip_masked_blocks and isinstance(q_offset, int):
+        if causal and isinstance(q_offset, int):
             last = q_offset + (qi + 1) * q_block - 1
             hi = min(n_kv, last // kv_block + 1)
         lo = 0
-        if window > 0 and skip_masked_blocks and isinstance(q_offset, int):
+        if window > 0 and isinstance(q_offset, int):
             first = max(q_offset + qi * q_block - window + 1, 0)
             lo = min(first // kv_block, hi)
 
@@ -252,9 +243,7 @@ def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l_new = l * corr + p.sum(-1)
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "bkgst,btkd->bkgsd", p.astype(score_dtype),
-                vb.astype(score_dtype)).astype(jnp.float32)
+            acc_new = acc * corr[..., None] + jnp.einsum("bkgst,btkd->bkgsd", p, vb)
             return (m_new, l_new, acc_new), None
 
         m0 = jnp.full((B, Hkv, G, q_block), NEG_INF, jnp.float32)
@@ -270,26 +259,56 @@ def attend_blockwise(q, k, v, *, q_offset, causal: bool, window: int = 0,
     return o.astype(q.dtype)
 
 
+BLOCK_Q, BLOCK_KV = 2048, 512      # blockwise attention's tiles
+FLASH_Q, FLASH_KV = 512, 512       # the flash kernel's tiles
+
+
+def attention_plan(sq: int, t: int, heads: int, *, flash: bool, backend: str,
+                   model_axis: int | None) -> tuple[str, int | None, int | None]:
+    """How :func:`attention` runs: ``(implementation, q tile, kv tile)``.
+
+    ``sq``/``t``: query and key lengths; ``heads``: the query's third axis
+    (kv heads for GQA, every head for MLA); ``flash``: the caller allows
+    the flash kernel and the call meets its preconditions; ``backend``:
+    ``jax.default_backend()``; ``model_axis``: the size of the sharding
+    environment's ``model`` axis, None without one.
+
+    The flash kernel runs on one TPU with no sharding environment (it has
+    no VJP and no partitioning rule).  A single query row, or a score
+    matrix of at most 2048^2 with at most 1024 keys, attends densely;
+    anything larger is blockwise.  Where the ``model`` axis does not divide
+    the heads, self-attention's queries are split over it by sequence
+    (context parallelism), so one q block spans them all: no q block
+    crosses a shard boundary.
+    """
+    if flash and backend == "tpu" and model_axis is None:
+        return "flash", FLASH_Q, FLASH_KV
+    if sq == 1 or (sq * t <= 2048 * 2048 and t <= 1024):
+        return "dense", None, None
+    q_block = sq if model_axis and heads % model_axis and sq == t else BLOCK_Q
+    return "blockwise", q_block, BLOCK_KV
+
+
 def attention(q, k, v, *, q_offset=0, causal=True, window=0, kv_valid_len=None,
-              soft_cap=0.0, strategy="auto", scale=None,
-              q_block=2048, kv_block=512, score_dtype=jnp.float32):
-    """Dispatch over attention strategies.  Shapes as in :func:`attend_dense`.
-    ``pallas`` (the flash kernel, TPU only: one device, self-attention, no
-    valid length or soft cap) takes ``q_block`` and ``kv_block`` as its
-    tiles."""
-    T = k.shape[1]
-    if strategy == "auto":
-        strategy = "blockwise" if (q.shape[1] * T > 2048 * 2048 or T > 1024) else "dense"
-    if strategy == "pallas":
+              soft_cap=0.0, scale=None, flash=False):
+    """Softmax attention, shapes as in :func:`attend_dense`, run as
+    :func:`attention_plan` chooses.  ``flash``: the caller's forward will
+    not be differentiated, so the flash kernel may take it where it runs
+    (no query offset, valid length or soft cap)."""
+    env = active_env()
+    impl, q_block, kv_block = attention_plan(
+        q.shape[1], k.shape[1], q.shape[2],
+        flash=flash and q_offset == 0 and kv_valid_len is None and not soft_cap,
+        backend=jax.default_backend(),
+        model_axis=None if env is None else axis_size("model", env))
+    if impl == "flash":
         from repro.kernels import ops
-        assert q_offset == 0 and kv_valid_len is None and not soft_cap
         return ops.flash_attention_bshd(q, k, v, causal=causal, window=window, scale=scale,
                                         block_q=q_block, block_k=kv_block)
-    if strategy == "blockwise":
+    if impl == "blockwise":
         return attend_blockwise(q, k, v, q_offset=q_offset, causal=causal, window=window,
                                 kv_valid_len=kv_valid_len, soft_cap=soft_cap, scale=scale,
-                                q_block=q_block, kv_block=kv_block,
-                                score_dtype=score_dtype)
+                                q_block=q_block, kv_block=kv_block)
     return attend_dense(q, k, v, q_offset=q_offset, causal=causal, window=window,
                         kv_valid_len=kv_valid_len, soft_cap=soft_cap, scale=scale)
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import active_env, axis_size, logical_constraint as shard
+from repro.distributed.sharding import axis_size, logical_constraint as shard
 from repro.models import layers as L
 from repro.models.params import block_cycle, build_params, init_params, lead_blocks
 
@@ -64,7 +64,8 @@ def _attn_shardings(cfg):
 
 
 @L.named_scope("attn")
-def gqa_full(cfg, p, x, positions, *, causal=True, window=0, rope=True):
+def gqa_full(cfg, p, x, positions, *, causal=True, window=0, rope=True,
+             attention=L.attention):
     """Full-sequence GQA/MQA/MHA attention."""
     B, S, _ = x.shape
     Hkv, G, Dh = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
@@ -74,19 +75,14 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0, rope=True):
     q = shard(q, q_ax)
     k = shard(k, kv_ax)
     v = shard(v, kv_ax)
-    # context-parallel runs keep q sequence-sharded -> single q block (no
-    # python q loop crossing shard boundaries); TP runs use q blocks with
-    # static causal truncation.
-    q_block = S if not _heads_shardable(cfg) else 2048
-    o = L.attention(q, k, v, q_offset=0, causal=causal, window=window, q_block=q_block,
-                    kv_block=cfg.attn_kv_block,
-                    score_dtype=jnp.dtype(cfg.attn_score_dtype))
+    o = attention(q, k, v, q_offset=0, causal=causal, window=window)
     o = o.reshape(B, S, cfg.num_heads, Dh)
     return _attn_out(p, o, x.dtype), (k, v)
 
 
 @L.named_scope("attn")
-def gqa_decode(cfg, p, x, pos, cache, *, window=0, rope=True, positions=None):
+def gqa_decode(cfg, p, x, pos, cache, *, window=0, rope=True, positions=None,
+               attention=L.attention):
     """Single-token attention against a per-slot ring cache {'k','v'}.
 
     ``pos``: (B,) int32 — per-sequence absolute position (continuous batching
@@ -108,14 +104,13 @@ def gqa_decode(cfg, p, x, pos, cache, *, window=0, rope=True, positions=None):
         kv_ax = ("batch", "kv_seq", None, "head_dim")
     k, v = shard(k, kv_ax), shard(v, kv_ax)
     valid = jnp.minimum(pos + 1, T)
-    o = L.attention(q, k, v, q_offset=0, causal=False,
-                    kv_valid_len=valid, strategy="dense")
+    o = attention(q, k, v, q_offset=0, causal=False, kv_valid_len=valid)
     o = o.reshape(B, 1, cfg.num_heads, Dh)
     return _attn_out(p, o, x.dtype), {"k": k, "v": v}
 
 
 @L.named_scope("attn")
-def cross_full(cfg, p, x, enc_out):
+def cross_full(cfg, p, x, enc_out, *, attention=L.attention):
     """Cross attention (whisper decoder): q from x, kv from encoder output."""
     B, S, _ = x.shape
     Hkv, G, Dh = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
@@ -128,32 +123,29 @@ def cross_full(cfg, p, x, enc_out):
         k = k + p["k"]["b"].astype(x.dtype)
         v = v + p["v"]["b"].astype(x.dtype)
     q = q.reshape(B, S, Hkv, G, Dh)
-    o = L.attention(q, k, v, q_offset=0, causal=False)
+    o = attention(q, k, v, q_offset=0, causal=False)
     return _attn_out(p, o.reshape(B, S, cfg.num_heads, Dh), x.dtype), (k, v)
 
 
 @L.named_scope("attn")
-def cross_decode(cfg, p, x, cache):
+def cross_decode(cfg, p, x, cache, *, attention=L.attention):
     B = x.shape[0]
     Hkv, G, Dh = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, p["q"]["w"].astype(x.dtype))
     if "b" in p["q"]:
         q = q + p["q"]["b"].astype(x.dtype)
     q = q.reshape(B, 1, Hkv, G, Dh)
-    o = L.attention(q, cache["ck"], cache["cv"], q_offset=0, causal=False,
-                    strategy="dense")
+    o = attention(q, cache["ck"], cache["cv"], q_offset=0, causal=False)
     return _attn_out(p, o.reshape(B, 1, cfg.num_heads, Dh), x.dtype)
 
 
 # --- MLA (deepseek) -------------------------------------------------------
 
-MLA_Q_BLOCK, MLA_KV_BLOCK = 512, 512     # the flash kernel's tiles at prefill
-
-
 @L.named_scope("attn")
-def mla_full(cfg, p, x, positions, *, prefill: bool = False):
+def mla_full(cfg, p, x, positions, *, prefill: bool = False, attention=L.attention):
     """Expanded-form MLA for train/prefill; returns compressed cache parts.
-    A ``prefill`` on one TPU attends with the flash kernel (forward only)."""
+    A ``prefill`` lets :func:`layers.attention` take the flash kernel
+    (forward only)."""
     B, S, _ = x.shape
     H = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -175,12 +167,8 @@ def mla_full(cfg, p, x, positions, *, prefill: bool = False):
     v = shard(v, ("batch", "seq", "heads", "head_dim"))
     # 128 heads make the float32 scores' passes through HBM the XLA path's
     # largest cost at prefill; on one TPU the flash kernel keeps them in VMEM.
-    tiles = {}
-    if prefill and jax.default_backend() == "tpu" and active_env() is None:
-        tiles = dict(strategy="pallas", q_block=MLA_Q_BLOCK, kv_block=MLA_KV_BLOCK)
-    o = L.attention(q_all, k_all, v, q_offset=0, causal=True,
-                    scale=L.attn_scale(cfg) / math.sqrt(dn + dr),
-                    score_dtype=jnp.dtype(cfg.attn_score_dtype), **tiles)
+    o = attention(q_all, k_all, v, q_offset=0, causal=True,
+                  scale=L.attn_scale(cfg) / math.sqrt(dn + dr), flash=prefill)
     o = o.reshape(B, S, H, dv)
     return _attn_out(p, o, x.dtype), (ckv, k_rope[:, :, 0, :])
 
@@ -226,8 +214,9 @@ def mla_decode(cfg, p, x, pos, cache):
 # ==========================================================================
 
 @L.named_scope()
-def apply_block_full(cfg, kind, p, h, aux, collect_cache):
-    """Returns (h, cache_out_or_None, aux_loss)."""
+def apply_block_full(cfg, kind, p, h, aux, collect_cache, *, attention=L.attention):
+    """Returns (h, cache_out_or_None, aux_loss).  ``attention`` runs every
+    attention of the block (the simulator passes its abstract operator)."""
     h = shard(h, ("batch", "seq_sp", "embed"))   # Megatron-SP residual stream
     positions = aux["positions"]
     zero = jnp.zeros((), jnp.float32)
@@ -248,7 +237,8 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
         return {"k": kc, "v": vc}
 
     if kind == "attn_ffn":
-        a, (k, v) = gqa_full(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), positions)
+        a, (k, v) = gqa_full(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), positions,
+                             attention=attention)
         h = h + a
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, kv_cache(k, v), zero
@@ -256,9 +246,10 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
     if kind in ("moe_attn_ffn", "mla_moe", "mla_ffn"):
         y = L.apply_norm(cfg, p["ln1"], h)
         if kind == "moe_attn_ffn":
-            a, (k, v) = gqa_full(cfg, p["attn"], y, positions)
+            a, (k, v) = gqa_full(cfg, p["attn"], y, positions, attention=attention)
         else:
-            a, (ckv, kr) = mla_full(cfg, p["attn"], y, positions, prefill=collect_cache)
+            a, (ckv, kr) = mla_full(cfg, p["attn"], y, positions, prefill=collect_cache,
+                                    attention=attention)
         h = h + a
         y = L.apply_norm(cfg, p["ln2"], h)
         if kind == "mla_ffn":
@@ -280,7 +271,7 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
 
     if kind == "griffin_attn":
         a, (k, v) = gqa_full(cfg, p["attn"], L.apply_norm(cfg, p["ln"], h), positions,
-                             window=cfg.window)
+                             window=cfg.window, attention=attention)
         h = h + a
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, kv_cache(k, v, window=cfg.window), zero
@@ -331,10 +322,10 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
 
     if kind == "xattn":
         a, (k, v) = gqa_full(cfg, p["self_attn"], L.apply_norm(cfg, p["ln1"], h), positions,
-                             rope=False)
+                             rope=False, attention=attention)
         h = h + a
         ca, (ck, cv) = cross_full(cfg, p["cross_attn"], L.apply_norm(cfg, p["ln2"], h),
-                                  aux["enc_out"])
+                                  aux["enc_out"], attention=attention)
         h = h + ca
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln3"], h))
         cache = None
@@ -345,7 +336,7 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
 
     if kind == "enc":
         a, _ = gqa_full(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), positions,
-                        causal=False, rope=False)
+                        causal=False, rope=False, attention=attention)
         h = h + a
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, None, zero
@@ -358,20 +349,21 @@ def apply_block_full(cfg, kind, p, h, aux, collect_cache):
 # ==========================================================================
 
 @L.named_scope()
-def apply_block_decode(cfg, kind, p, h, cache, aux):
-    """Returns (h, new_cache)."""
+def apply_block_decode(cfg, kind, p, h, cache, aux, *, attention=L.attention):
+    """Returns (h, new_cache); ``attention`` as in :func:`apply_block_full`."""
     pos = aux["pos"]
     positions = aux.get("decode_positions")
 
     if kind == "attn_ffn":
         a, c = gqa_decode(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), pos, cache,
-                          positions=positions)
+                          positions=positions, attention=attention)
         h = h + a
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, c
 
     if kind == "moe_attn_ffn":
-        a, c = gqa_decode(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), pos, cache)
+        a, c = gqa_decode(cfg, p["attn"], L.apply_norm(cfg, p["ln1"], h), pos, cache,
+                          attention=attention)
         h = h + a
         m, _ = L.moe_ffn(cfg, p["moe"], L.apply_norm(cfg, p["ln2"], h))
         return h + m, c
@@ -385,7 +377,7 @@ def apply_block_decode(cfg, kind, p, h, cache, aux):
 
     if kind == "griffin_attn":
         a, c = gqa_decode(cfg, p["attn"], L.apply_norm(cfg, p["ln"], h), pos, cache,
-                          window=cfg.window)
+                          window=cfg.window, attention=attention)
         h = h + a
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
         return h, c
@@ -431,9 +423,10 @@ def apply_block_decode(cfg, kind, p, h, cache, aux):
     if kind == "xattn":
         self_cache = {"k": cache["k"], "v": cache["v"]}
         a, c = gqa_decode(cfg, p["self_attn"], L.apply_norm(cfg, p["ln1"], h), pos,
-                          self_cache, rope=False)
+                          self_cache, rope=False, attention=attention)
         h = h + a
-        h = h + cross_decode(cfg, p["cross_attn"], L.apply_norm(cfg, p["ln2"], h), cache)
+        h = h + cross_decode(cfg, p["cross_attn"], L.apply_norm(cfg, p["ln2"], h), cache,
+                             attention=attention)
         h = h + L.ffn(cfg, p["mlp"], L.apply_norm(cfg, p["ln3"], h))
         return h, {"k": c["k"], "v": c["v"], "ck": cache["ck"], "cv": cache["cv"]}
 

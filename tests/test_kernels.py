@@ -1,9 +1,9 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""The Pallas flash kernel vs pure-jnp oracles: shape/dtype sweeps (interpret
+mode); and which attention implementation layers.attention chooses."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention as fa_raw
@@ -52,48 +52,31 @@ def test_flash_attention_value_width(dtype, tol):
                                atol=tol, rtol=tol)
 
 
-DEC_CASES = [
-    (2, 4, 2, 256, 64, jnp.float32, 2e-6),
-    (1, 8, 1, 300, 64, jnp.float32, 2e-6),   # MQA, ragged splits
-    (2, 4, 4, 512, 128, jnp.bfloat16, 2e-2),
-]
+# (sq, t, heads, flash, backend, model_axis) -> (implementation, q tile, kv tile):
+# one case per call site of layers.attention
+PLAN_CASES = {
+    "decode with a valid length": ((1, 2048, 8, False, "tpu", None), ("dense", None, None)),
+    "one query row, no valid length": ((1, 1500, 20, False, "cpu", None), ("dense", None, None)),
+    "phi4 prefill at 896": ((896, 896, 8, False, "tpu", None), ("dense", None, None)),
+    "T = 1152": ((1152, 1152, 8, False, "tpu", None), ("blockwise", 2048, 512)),
+    "train at 2048": ((2048, 2048, 8, False, "tpu", None), ("blockwise", 2048, 512)),
+    "context parallel": ((4096, 4096, 8, False, "cpu", 16), ("blockwise", 4096, 512)),
+    "cross attention on a mesh": ((4096, 1500, 20, False, "cpu", 16), ("blockwise", 2048, 512)),
+    "MLA prefill on one TPU": ((4096, 4096, 128, True, "tpu", None), ("flash", 512, 512)),
+    "MLA prefill under a mesh": ((4096, 4096, 128, True, "tpu", 1), ("blockwise", 2048, 512)),
+    "MLA train forward on a TPU": ((4096, 4096, 128, False, "tpu", None), ("blockwise", 2048, 512)),
+    "MLA prefill on the CPU": ((1024, 1024, 128, True, "cpu", None), ("dense", None, None)),
+}
 
 
-@pytest.mark.parametrize("case", DEC_CASES)
-def test_decode_attention_sweep(case):
-    B, H, Hkv, T, D, dtype, tol = case
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (B, H, D), jnp.float32).astype(dtype)
-    k = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32).astype(dtype)
-    v = jax.random.normal(ks[2], (B, Hkv, T, D), jnp.float32).astype(dtype)
-    vl = jnp.asarray([T // 2, T][:B], jnp.int32)
-    out = ops.decode_attention(q, k, v, vl, interpret=True)
-    want = ref.decode_attention_ref(q, k, v, kv_valid_len=vl)
-    np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
-                               atol=tol, rtol=tol)
-
-
-@settings(max_examples=15, deadline=None)
-@given(rows=st.integers(1, 300), d=st.sampled_from([128, 256, 512]),
-       offset=st.booleans(), bf16=st.booleans())
-def test_rmsnorm_property(rows, d, offset, bf16):
-    dtype = jnp.bfloat16 if bf16 else jnp.float32
-    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32).astype(dtype)
-    w = jax.random.normal(jax.random.PRNGKey(d), (d,), jnp.float32) * 0.1 + 1.0
-    out = ops.rmsnorm(x, w, offset=offset, interpret=True)
-    want = ref.rmsnorm_ref(x, w, offset=offset)
-    tol = 3e-2 if bf16 else 2e-6
-    np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
-                               atol=tol, rtol=tol)
-
-
-def test_rmsnorm_fused_residual():
-    x = jax.random.normal(jax.random.PRNGKey(0), (4, 10, 256))
-    r = jax.random.normal(jax.random.PRNGKey(1), (4, 10, 256))
-    w = jnp.ones((256,))
-    out = ops.rmsnorm_residual(x, r, w, interpret=True)
-    want = ref.rmsnorm_ref(x, w, residual=r)
-    np.testing.assert_allclose(out, want, atol=2e-6, rtol=2e-6)
+@pytest.mark.parametrize("case", PLAN_CASES)
+def test_attention_plan(case):
+    """layers.attention_plan alone chooses the implementation and its tiles,
+    from the shapes, the backend and the mesh."""
+    from repro.models import layers as L
+    (sq, t, heads, flash, backend, model_axis), want = PLAN_CASES[case]
+    assert L.attention_plan(sq, t, heads, flash=flash, backend=backend,
+                            model_axis=model_axis) == want
 
 
 def test_flash_matches_model_layout():

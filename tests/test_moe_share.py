@@ -169,30 +169,37 @@ def test_deepseek_v3_stack_and_size():
     assert lead_blocks(phi4) == () and block_cycle(phi4)[1] == phi4.num_layers
 
 
-def test_mla_prefill_takes_the_flash_kernel_on_one_tpu(monkeypatch):
-    """On one TPU the prefill's latent attention asks for the flash kernel;
-    the forward that training differentiates keeps the XLA path.  The
-    backend is described as a TPU and the attention recorded, then run on
-    the CPU's path."""
-    from repro.models import Model
-    from repro.models.model import MLA_KV_BLOCK, MLA_Q_BLOCK
+def test_mla_prefill_takes_the_flash_kernel_on_one_tpu():
+    """On one TPU the prefill's latent attention takes the flash kernel with
+    its tiles; the forward that training differentiates keeps the XLA path.
+    Each MLA block is traced with a recording attention passed in as its
+    ``attention``, and every call read by ``layers.attention_plan`` as one
+    TPU with no mesh would read it."""
+    from repro.models import abstract_params
+    from repro.models.model import apply_block_full
     cfg = get_tiny_config("deepseek-v3-671b")
-    asked, real = [], L.attention
+    params = abstract_params(cfg)
+    B, S = 1, 8
+    h = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.dtype(cfg.dtype))
+    aux = {"positions": jnp.zeros((B, S), jnp.int32), "cache_len": 16}
+    blocks = [(lead_blocks(cfg)[0], params["blocks"]["lead"][0]),
+              (block_cycle(cfg)[0][0], jax.tree.map(
+                  lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                  params["blocks"]["cycle"][0]))]
+    for prefill, want in ((True, ("flash", L.FLASH_Q, L.FLASH_KV)),
+                          (False, ("dense", None, None))):
+        plans = []
 
-    def record(*args, **kw):
-        asked.append((kw.get("strategy", "auto"), kw.get("q_block"), kw.get("kv_block")))
-        return real(*args, **dict(kw, strategy="auto"))
+        def record(q, k, v, **kw):
+            plans.append(L.attention_plan(q.shape[1], k.shape[1], q.shape[2],
+                                          flash=kw.get("flash", False),
+                                          backend="tpu", model_axis=None))
+            return L.attention(q, k, v, **kw)
 
-    monkeypatch.setattr(L, "attention", record)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    m = Model(cfg)
-    params = m.init(jax.random.PRNGKey(0))
-    toks = jnp.zeros((1, 8), jnp.int32)
-    m.prefill(params, {"tokens": toks}, cache_len=16)
-    assert asked and set(asked) == {("pallas", MLA_Q_BLOCK, MLA_KV_BLOCK)}
-    asked.clear()
-    m.forward(params, {"tokens": toks})
-    assert asked and {s for s, _, _ in asked} == {"auto"}
+        for kind, p in blocks:
+            jax.eval_shape(lambda p, h: apply_block_full(cfg, kind, p, h, aux, prefill,
+                                                         attention=record), p, h)
+        assert plans == [want] * len(blocks), (prefill, plans)
 
 
 def test_moe_share_sharded_matches_local():

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.models import Model, zero_cache
+from repro.models import Model, layers as L, zero_cache
 
 CFG = get_config("phi4-mini-3.8b")
 HBM_BYTES = 16e9            # one TPU v5e chip
@@ -57,37 +57,25 @@ def _on(tree, sharding):
 
 
 def test_flash_attention_compiles(one_chip):
-    H, Hkv, S, D = CFG.num_heads, CFG.num_kv_heads, 2048, CFG.head_dim
-    compiled = ops.flash_attention.lower(
-        _sds((1, H, S, D), one_chip), _sds((1, Hkv, S, D), one_chip),
-        _sds((1, Hkv, S, D), one_chip)).compile()
+    """phi4-mini's prefill attention at 2048 tokens, GQA 24/8 x 128, with
+    the kernel tiles ``layers.attention`` uses."""
+    Hkv, G, S, D = CFG.num_kv_heads, CFG.q_per_kv, 2048, CFG.head_dim
+    compiled = ops.flash_attention_bshd.lower(
+        _sds((1, S, Hkv, G, D), one_chip), _sds((1, S, Hkv, D), one_chip),
+        _sds((1, S, Hkv, D), one_chip), causal=True,
+        block_q=L.FLASH_Q, block_k=L.FLASH_KV).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_mla_prefill_flash_attention_compiles(one_chip):
     """DeepSeek-V3's prefill attention at its published widths: 128 heads,
-    q/k 192 wide, v 128, a 4096-token prompt, the tiles ``mla_full`` uses."""
-    from repro.models.model import MLA_KV_BLOCK, MLA_Q_BLOCK
+    q/k 192 wide, v 128, a 4096-token prompt, the tiles ``layers.attention``
+    uses."""
     S, H = 4096, 128
     compiled = ops.flash_attention_bshd.lower(
         _sds((1, S, H, 1, 192), one_chip), _sds((1, S, H, 192), one_chip),
         _sds((1, S, H, 128), one_chip), causal=True, scale=0.1,
-        block_q=MLA_Q_BLOCK, block_k=MLA_KV_BLOCK).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_decode_attention_compiles(one_chip):
-    B, H, Hkv, T, D = 4, CFG.num_heads, CFG.num_kv_heads, 2048, CFG.head_dim
-    compiled = ops.decode_attention.lower(
-        _sds((B, H, D), one_chip), _sds((B, Hkv, T, D), one_chip),
-        _sds((B, Hkv, T, D), one_chip),
-        _sds((B,), one_chip, jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_rmsnorm_compiles(one_chip):
-    compiled = ops.rmsnorm.lower(_sds((2048, CFG.d_model), one_chip),
-                                 _sds((CFG.d_model,), one_chip)).compile()
+        block_q=L.FLASH_Q, block_k=L.FLASH_KV).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -104,5 +92,5 @@ def test_phi4_decode_step_fits_one_chip(one_chip):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 2 * CFG.param_count()  # weights + cache
     assert used < HBM_BYTES, used
-    # the model path does not call the Pallas kernels (yet)
+    # the decode step runs no Pallas kernel
     assert "tpu_custom_call" not in compiled.as_text()

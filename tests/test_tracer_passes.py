@@ -1,5 +1,7 @@
-"""Tracer + pass invariants: flops accounting, TP/EP rewrites, fusion,
-quantization, recompute, pipeline schedules."""
+"""Tracer + pass invariants: flops accounting, model ingest, TP/EP rewrites,
+fusion, quantization, recompute, pipeline schedules."""
+import threading
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -56,6 +58,59 @@ def test_scan_repeat_multiplier():
     wa = jax.ShapeDtypeStruct((32, 32), jnp.float32)
     g = tracer.trace(f, xa, wa)
     assert g.total("flops") == 9 * 2 * 32 * 32 * 32
+
+
+# attentions per block kind of whisper: the decoder's self and cross
+# attention, the encoder's self-attention
+_WHISPER_ATTENTIONS = {"xattn": 2, "enc": 1, "head": 0}
+
+
+@pytest.mark.parametrize("mode", ["train", "prefill", "decode"])
+def test_ingest_traces_each_attention_as_one_operator(mode):
+    """``block_graphs`` hands the block functions the abstract attention:
+    each attention is one ``charon_attention`` node, with its
+    ``charon_attention_bwd`` (2.5x the flops) in the joint graph.
+    ``layers.attention`` is never reassigned, so the model run in another
+    thread while the simulator traces gives the logits it gives alone."""
+    from repro.configs import get_tiny_config
+    from repro.core.model_ingest import block_graphs
+    from repro.models import Model, layers as L
+    cfg = get_tiny_config("whisper-large-v3")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.arange(8, dtype=jnp.int32)[None],
+             "frame_embeds": jax.random.normal(jax.random.PRNGKey(1),
+                                               (1, cfg.encoder_seq, cfg.d_model))}
+
+    def forward():      # a new function each time: traced anew, calling attention
+        return jax.jit(lambda p, b: model.forward(p, b)[0])(params, batch)
+
+    alone = forward()
+    real, done, traced = L.attention, threading.Event(), []
+
+    def trace_until_done():
+        while True:
+            traced.append(block_graphs(cfg, 2, 16, mode, cache_len=32))
+            if done.is_set():
+                return
+
+    t = threading.Thread(target=trace_until_done)
+    t.start()
+    try:
+        during = forward()
+    finally:
+        done.set()
+        t.join(timeout=300)
+    assert not t.is_alive() and traced
+    assert L.attention is real
+    assert jnp.array_equal(during, alone)
+    for bg in traced[-1].all_blocks():
+        n = _WHISPER_ATTENTIONS[bg.kind]
+        fwd = sorted(node.flops for node in bg.fwd if node.kind == "attention")
+        assert len(fwd) == n, (bg.kind, fwd)
+        if bg.joint is not None:
+            joint = sorted(node.flops for node in bg.joint if node.kind == "attention")
+            assert joint == sorted(fwd + [2.5 * f for f in fwd]), (bg.kind, joint)
 
 
 def test_tp_pass_divides_and_inserts_collectives():
